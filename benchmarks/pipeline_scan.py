@@ -123,6 +123,9 @@ def run(csv_rows: list):
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
     env.pop("XLA_FLAGS", None)  # the worker pins its own device count
+    # the worker measures virtual CPU devices; it must never contend with
+    # this process (which has imported JAX) for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _WORKER],
         capture_output=True, text=True, timeout=1800, env=env,

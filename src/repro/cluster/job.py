@@ -600,8 +600,10 @@ class _ShardStager:
                     box.clear()
 
             t = threading.Thread(target=_stage, name=f"shard-stage-{idx}", daemon=True)
+            # started before it is published: a worker claiming this shard
+            # at once must find a thread it can join
+            t.start()
             self._staged[idx] = (t, box, dev)
-        t.start()
 
 
 class _WriterPool:

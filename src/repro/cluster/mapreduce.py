@@ -32,7 +32,6 @@ from typing import Any, Callable, Sequence
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import compat
 from repro.core import scan, topk
@@ -364,12 +363,12 @@ def search_mesh(
         )
         return topk.merge_across_lex(state, axis_names)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_job,
         mesh=mesh,
         in_specs=(q_specs, docs_specs, stats_specs),
         out_specs=topk.TopKState(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     fn = jax.jit(sharded)
     with _MESH_CACHE_LOCK:

@@ -1,75 +1,15 @@
-"""JAX version-compatibility shims.
+"""One import path for the JAX mesh and collective names the repo calls.
 
-The repo targets the current JAX API but must run on the container's pinned
-release. Policy: call sites use the *new* spelling; this module backfills it
-when the installed JAX predates it.
-
-``set_mesh(mesh)`` — context manager activating ``mesh`` as the ambient mesh.
-Resolution order: native ``jax.set_mesh`` → ``jax.sharding.use_mesh`` →
-``Mesh`` itself as a context manager (the legacy global-mesh context, which
-is what pjit-era JAX used for exactly this purpose). Importing this module
-also installs the fallback *as* ``jax.set_mesh`` so existing
-``jax.set_mesh(...)`` call sites (tests, examples) work unmodified.
+``set_mesh`` (the ambient-mesh context manager), ``axis_size`` (a concrete
+int inside ``shard_map``) and a differentiable ``optimization_barrier`` are
+native in the installed JAX; call sites import them from here so a future
+API move is a one-line change.
 """
 
 from __future__ import annotations
 
 import jax
 
-_NATIVE_SET_MESH = getattr(jax, "set_mesh", None)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` with fallbacks for older JAX releases."""
-    if _NATIVE_SET_MESH is not None:
-        return _NATIVE_SET_MESH(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager
-
-
-if _NATIVE_SET_MESH is None:
-    jax.set_mesh = set_mesh
-
-
-_NATIVE_AXIS_SIZE = getattr(jax.lax, "axis_size", None)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` with a fallback for older JAX.
-
-    ``psum`` of a Python literal constant-folds, so the fallback returns the
-    same concrete int as the native call (usable in Python control flow).
-    """
-    if _NATIVE_AXIS_SIZE is not None:
-        return _NATIVE_AXIS_SIZE(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-if _NATIVE_AXIS_SIZE is None:
-    jax.lax.axis_size = axis_size
-
-
-def _barrier_differentiable() -> bool:
-    try:  # abstract trace only — no compile, no device work
-        jax.jvp(jax.lax.optimization_barrier, (0.0,), (0.0,))
-        return True
-    except Exception:
-        return False
-
-
-if _barrier_differentiable():
-    optimization_barrier = jax.lax.optimization_barrier
-else:
-    # Older JAX has no differentiation rule for optimization_barrier; the
-    # barrier is identity-valued, so its JVP is the identity on tangents.
-    @jax.custom_jvp
-    def optimization_barrier(x):
-        """``jax.lax.optimization_barrier`` usable under autodiff."""
-        return jax.lax.optimization_barrier(x)
-
-    @optimization_barrier.defjvp
-    def _optimization_barrier_jvp(primals, tangents):
-        (x,), (t,) = primals, tangents
-        return jax.lax.optimization_barrier(x), t
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size
+optimization_barrier = jax.lax.optimization_barrier

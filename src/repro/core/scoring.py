@@ -97,8 +97,8 @@ def term_frequencies_dense(q_tokens: jax.Array, d_tokens: jax.Array) -> jax.Arra
 # (the dominant chunk cost) plus a cheap per-term **epilogue**: a declarative
 # spec small enough to evaluate on the VPU inside the fused Pallas kernel
 # (`repro.kernels.lexical_scan`) and on the pure-JAX fallback path with the
-# *same code* (`apply_epilogue`), which is what makes kernel-vs-host parity
-# bitwise for the scores. The static half (`EpilogueMode`) selects the
+# *same code* (`epilogue_scores`), so both paths run the same operations in
+# the same order on the same exact tf. The static half (`EpilogueMode`) selects the
 # per-term transform and the doc-length treatment; the traced half
 # (`LexicalEpilogue`) is a per-term weight table plus two doc-length
 # normalization scalars.
@@ -143,29 +143,59 @@ def apply_epilogue(
 ) -> jax.Array:
     """Score a block from its term frequencies: ``[n_q, L_q, n_d] -> [n_q, n_d]``.
 
-    Shared verbatim by the Pallas kernel epilogue and the pure-JAX fold, so
-    the two paths agree bitwise given the same ``tf``. VPU-only ops: no
-    gathers, no matmuls — the collection statistics were already folded into
-    ``ep.weights`` when the epilogue was built.
+    The host-layout entry to :func:`epilogue_scores`, the code the Pallas
+    kernel epilogue runs too.
     """
-    d_len_f = jnp.maximum(d_len.astype(jnp.float32), 1.0)  # [n_d]
-    w = ep.weights[:, :, None]  # [n_q, L_q, 1]
+    return epilogue_scores(
+        mode,
+        jnp.swapaxes(ep.weights, 0, 1)[:, :, None],
+        ep.alpha,
+        ep.beta,
+        jnp.swapaxes(tf, 0, 1),
+        d_len[None, :],
+    )
+
+
+def epilogue_scores(
+    mode: EpilogueMode,
+    w: jax.Array,  # [L_q, n_q, 1] float32 per-term weights
+    alpha: jax.Array,
+    beta: jax.Array,
+    tf: jax.Array,  # [L_q, n_q, n_d] float32, term-major
+    d_len: jax.Array,  # [1, n_d] int32
+) -> jax.Array:
+    """Term-major epilogue ``-> [n_q, n_d]``, shared verbatim by the Pallas
+    kernel and the pure-JAX fold.
+
+    Every array stays 2-D per query term (docs along lanes in the kernel),
+    and the sum over query terms is an explicit left fold, so XLA and Mosaic
+    evaluate the same operations in the same order. VPU-only ops: no
+    gathers, no matmuls — the collection statistics were already folded into
+    the weights when the epilogue was built.
+    """
+    d_len_f = jnp.maximum(d_len.astype(jnp.float32), 1.0)  # [1, n_d]
     if mode.mode == "ql":
-        per_term = jnp.log1p(w * tf / d_len_f[None, None, :])
+        def per_term(j):
+            return jnp.log1p(w[j] * tf[j] / d_len_f)
     elif mode.mode == "bm25":
-        norm = ep.alpha + ep.beta * d_len.astype(jnp.float32)
-        per_term = w * tf / (tf + norm[None, None, :])
+        norm = alpha + beta * d_len.astype(jnp.float32)
+
+        def per_term(j):
+            return w[j] * tf[j] / (tf[j] + norm)
     elif mode.mode == "tfidf":
-        per_term = w * jnp.log1p(tf)
+        def per_term(j):
+            return w[j] * jnp.log1p(tf[j])
     else:
         raise ValueError(f"unknown epilogue mode {mode.mode!r}")
-    score = jnp.sum(per_term, axis=1)  # [n_q, n_d]
+    score = per_term(0)
+    for j in range(1, tf.shape[0]):
+        score = score + per_term(j)
     if mode.length_prior:
-        score = score + jnp.log(d_len_f)[None, :]
+        score = score + jnp.log(d_len_f)
     if mode.length_norm == "rsqrt":
-        score = score / jnp.sqrt(d_len_f)[None, :]
+        score = score / jnp.sqrt(d_len_f)
     # padded corpus rows (len 0) must never enter the top-k
-    return jnp.where((d_len > 0)[None, :], score, -jnp.inf)
+    return jnp.where(d_len > 0, score, -jnp.inf)
 
 
 def ql_lm_epilogue(

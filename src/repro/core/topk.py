@@ -104,14 +104,14 @@ def merge_lex(a: TopKState, b: TopKState) -> TopKState:
     combiner), so the padding preserves (score desc, id asc) sortedness.
     """
     # local import: core stays importable when the Pallas toolchain is absent
-    from repro.kernels.score_topk import _pad_desc, bitonic_merge_desc
+    from repro.kernels.score_topk import bitonic_merge_desc, pad_lanes
 
     if a.scores.shape != b.scores.shape:
         raise ValueError(f"merge_lex shape mismatch: {a.scores.shape} != {b.scores.shape}")
     k = a.k
     width = 1 if k <= 1 else 1 << (k - 1).bit_length()  # next pow2
-    a_s, a_i = _pad_desc(a.scores, a.ids, width)
-    b_s, b_i = _pad_desc(b.scores, b.ids, width)
+    a_s, a_i = pad_lanes(a.scores, a.ids, width)
+    b_s, b_i = pad_lanes(b.scores, b.ids, width)
     s, i = bitonic_merge_desc(a_s, a_i, b_s, b_i)
     return TopKState(scores=s[..., :k], ids=i[..., :k])
 

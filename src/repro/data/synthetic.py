@@ -13,8 +13,11 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scoring
 from repro.core.scoring import PAD_TOKEN
 
 
@@ -45,10 +48,8 @@ def make_corpus(
     lengths = rng.integers(min_len, max_len + 1, size=n_docs).astype(np.int32)
     tokens = np.full((n_docs, max_len), PAD_TOKEN, np.int32)
     flat = _zipf_tokens(rng, int(lengths.sum()), vocab, alpha)
-    pos = 0
-    for i, l in enumerate(lengths):
-        tokens[i, :l] = flat[pos : pos + l]
-        pos += l
+    # row-major boolean fill: doc i takes the next lengths[i] tokens of flat
+    tokens[np.arange(max_len)[None, :] < lengths[:, None]] = flat
     return Corpus(tokens=tokens, lengths=lengths)
 
 
@@ -72,6 +73,32 @@ def make_queries(
     return q
 
 
+_COUNT_CHUNK = 16384  # docs per device call of the query-term count
+
+
+def _query_term_counts(queries: np.ndarray, tokens: np.ndarray) -> np.ndarray:
+    """``[n_q, n_docs]`` occurrences of each query's terms (with their
+    multiplicity in the query) in each doc: the scan's own tf reduction,
+    summed over query slots, run chunk by chunk on the default device.
+    Exact integers, so the ranking below does not depend on where it ran."""
+    n_docs, max_len = tokens.shape
+    pad = -n_docs % _COUNT_CHUNK if n_docs > _COUNT_CHUNK else 0
+    if pad:
+        tokens = np.concatenate([tokens, np.full((pad, max_len), PAD_TOKEN, np.int32)])
+    step = min(n_docs, _COUNT_CHUNK)
+    q = jnp.asarray(queries)
+    parts = [
+        np.asarray(_count_chunk(q, jnp.asarray(tokens[a : a + step])))
+        for a in range(0, tokens.shape[0], step)
+    ]
+    return np.concatenate(parts, axis=1)[:, :n_docs]
+
+
+@jax.jit
+def _count_chunk(queries, tokens):
+    return scoring.term_frequencies(queries, tokens).sum(axis=1).astype(jnp.int32)
+
+
 def _density_ranked(
     corpus: Corpus, queries: np.ndarray, per_query: int, seed: int
 ) -> list[np.ndarray]:
@@ -82,13 +109,10 @@ def _density_ranked(
     ``make_graded_qrels(...) > 0 == make_qrels(...)`` true by construction."""
     rng = np.random.default_rng(seed)
     lengths = np.maximum(corpus.lengths, 1)
+    counts = _query_term_counts(queries, corpus.tokens)
     ranked = []
     for qi in range(queries.shape[0]):
-        terms = queries[qi][queries[qi] != PAD_TOKEN]
-        density = np.zeros(corpus.tokens.shape[0], np.float64)
-        for t in terms:
-            density += (corpus.tokens == t).sum(-1)
-        density = density / lengths
+        density = counts[qi] / lengths
         density += rng.normal(0, 1e-9, density.shape)  # tie-break
         top = np.argsort(-density)[:per_query]
         ranked.append(top[density[top] > 0])

@@ -85,6 +85,7 @@ class ExperimentSpec:
     n_queries: int = 64
     vocab: int = 8192
     max_doc_len: int = 64
+    max_q_len: int = 4  # query terms at most (queries are sampled from docs)
     k: int = 20
     chunk_size: int = 512
     segment_chunks: int = 4  # chunks per checkpoint segment

@@ -55,9 +55,20 @@ def prepare_collection(spec: ExperimentSpec, *, seed: int = 0) -> Collection:
         vocab=spec.vocab,
         chunk_size=min(spec.chunk_size, spec.n_docs),
     )
-    queries = synthetic.make_queries(corpus, n_queries=spec.n_queries, seed=seed + 1)
+    queries = synthetic.make_queries(
+        corpus, n_queries=spec.n_queries, max_q_len=spec.max_q_len, seed=seed + 1
+    )
     qrels = synthetic.make_graded_qrels(corpus, queries, per_query=25, seed=seed + 2)
     return Collection(corpus=corpus, stats=stats, queries=queries, qrels=qrels)
+
+
+def _device_of(x) -> str | None:
+    """``platform:id`` of the device holding a shard's final state (None
+    for a host array: a resumed shard that had nothing left to fold)."""
+    if not isinstance(x, jax.Array):
+        return None
+    (dev,) = x.devices()
+    return f"{dev.platform}:{dev.id}"
 
 
 def run_filename(variant: str) -> str:
@@ -229,7 +240,9 @@ def _run_experiment_traced(
             collection if collection is not None else prepare_collection(spec, seed=seed)
         )
     scorers = spec.scorers()
-    docs = (jnp.asarray(coll.corpus.tokens), jnp.asarray(coll.corpus.lengths))
+    # the corpus stays on the host: each shard's segments are staged to the
+    # device that folds them, so no device ever holds another shard's rows
+    docs = (np.asarray(coll.corpus.tokens), np.asarray(coll.corpus.lengths))
     # pack on the producer: token segments shrink to the tuned width here,
     # before sharding/staging, and every consumer decodes exactly — run
     # files stay byte-identical to the unpacked oracle (the pack contract)
@@ -243,7 +256,7 @@ def _run_experiment_traced(
         )
         if isinstance(packed, packing.PackedCorpus):
             pack_resolved = packed.spec.mode
-            docs = jax.tree.map(jnp.asarray, packed)
+            docs = packed
 
     # the tuned chunk replaces the spec's *for the scan fold only* (stats
     # preparation keeps the declared chunking — stats bytes depend on it);
@@ -367,6 +380,7 @@ def _run_experiment_traced(
                     "segments_total": r.segments_total,
                     "segments_run": r.segments_run,
                     "resumed_from": r.resumed_from,
+                    "device": _device_of(r.state.scores),
                 }
                 for r in job.shard_results
             ],

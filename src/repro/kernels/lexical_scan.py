@@ -1,34 +1,40 @@
 """Fused Pallas lexical-scan kernel — the paper's *actual* hot loop in VMEM.
 
 MIREX's headline claim is that sequentially scanning raw documents is fast
-enough for large-scale IR experiments; this kernel makes the raw-token scan
-bandwidth-bound on the document stream, the way the paper argues it should
-be. Each TPU grid step streams one ``[block_d, L_d]`` document-token tile
-HBM→VMEM and:
+enough for large-scale IR experiments. Each TPU grid step streams one
+``[block_d, L_d]`` document-token tile HBM→VMEM and:
 
-1. **tf reduction on-chip** — query-term frequencies are accumulated by
-   reducing over ``L_d`` in ``tile_d``-wide sub-tiles, so peak live memory
-   is ``O(n_q · L_q · block_d · tile_d)`` and the rank-4
-   ``[n_q, L_q, n_d, L_d]`` equality cross-product never exists anywhere.
+1. **tf reduction on-chip** — the tile is transposed into a ``[L_d,
+   block_d]`` VMEM scratch (documents along lanes). Queries are taken
+   ``ROWS`` (one sublane tile) at a time; each document position is
+   compared against the tile's term-major column of query slots,
+   ``[L_q·ROWS, 1] == [1, block_d]``, accumulated in int32. tf is an exact
+   integer sum, so its order is free; ``tile_d`` positions are unrolled per
+   loop step. The rank-4 ``[n_q, L_q, n_d, L_d]`` cross-product never
+   exists; the live tf block is ``[L_q·ROWS, block_d]``.
 2. **scorer epilogues on the VPU** — each model in the grid applies its
-   declarative epilogue spec (`scoring.EpilogueMode` +
-   weight table / normalization scalars) to the *shared* tf block via
-   `scoring.apply_epilogue` — literally the same code the pure-JAX fallback
-   runs, so kernel-vs-host score parity is bitwise given the same tf.
+   declarative epilogue spec to the *shared* tf block via
+   `scoring.epilogue_scores`, literally the same code the pure-JAX fold
+   runs, with the sum over query terms as the same explicit left fold.
 3. **resident top-k fold** — each model's block scores fold into a resident
-   ``[n_models, n_q, k]`` state with the k-bounded bitonic combiner
-   (`score_topk.bitonic_merge_desc`): the output refs double as the running
-   state because the TPU grid executes sequentially (combiner semantics).
+   ``[n_models, n_q, W]`` state with the k-bounded bitonic combiner
+   (`score_topk.fold_block`): the output refs double as the running state
+   because the TPU grid executes sequentially (combiner semantics).
 
 Because the tf reduction — the dominant cost of a raw-token chunk — is
 computed once per tile and shared by every epilogue, a whole **model grid
-scans in a single kernel pass**: PR 2's experiment-side amortization
-(claim C1 on the model axis), moved from the XLA path into VMEM.
+scans in a single kernel pass** (claim C1 on the model axis, in VMEM).
 
-BlockSpecs: queries ``[n_q, L_q]``, weights ``[n_models, n_q, L_q]`` and
-normalization scalars ``[n_models, 2]`` are resident across steps; doc
-tokens ``[block_d, L_d]`` and lengths ``[1, block_d]`` are streamed;
-outputs ``[n_models, n_q, k]`` are pinned to block (0, 0, 0).
+The comparisons cost ``n_q·L_q·L_d`` per document against ``L_d`` streamed
+tokens, so at ``scan_50q`` widths the kernel is bound by the VPU, not by the
+document stream.
+
+BlockSpecs: the query-slot column and the weights (``[n_models, n_q/ROWS,
+L_q, ROWS, 1]``) are resident across steps, the ``(alpha, beta)`` scalars
+sit in SMEM; doc tokens ``[block_d, L_d]`` and lengths ``[1, block_d]`` are
+streamed; outputs ``[n_models, n_q, W]`` are pinned to block (0, 0, 0) and
+cut to ``(n_q, k)`` by the wrapper, which pads the queries to whole row
+tiles. Compiled alignment wants ``block_d % 128 == 0``.
 """
 
 from __future__ import annotations
@@ -38,88 +44,89 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
-from repro.core.pipeline import next_pow2
-from repro.core.scoring import PAD_TOKEN, EpilogueMode, LexicalEpilogue
-from repro.core.scoring import apply_epilogue
-from repro.kernels.score_topk import _pad_desc, bitonic_merge_desc
+from repro.core.scoring import PAD_TOKEN, EpilogueMode, epilogue_scores
+from repro.kernels.score_topk import ROWS, fold_block, pad_rows, state_width
 
 
-def _block_term_frequencies(q_tok, d_tok, *, tile_d: int) -> jax.Array:
-    """On-chip tf for one doc tile: ``[n_q, L_q], [block_d, L_d] -> [n_q, L_q, block_d]``.
+def _block_term_frequencies(q_col, d_t_ref, *, tile_d: int) -> jax.Array:
+    """On-chip tf for one doc tile: ``[S, 1] x [L_d, block_d] -> [S, block_d]``
+    int32 for ``S`` query slots.
 
-    Reduces over ``L_d`` in ``tile_d`` sub-tiles with an int32 accumulator —
-    identical accumulation order (and therefore identical integers) to the
-    tiled host fallback in `scoring.term_frequencies`. ``L_d`` must be a
-    multiple of ``tile_d`` (the wrapper pads with PAD_TOKEN); query pads are
-    pre-remapped by the wrapper so no validity mask is needed here.
+    Query pads are pre-remapped by the wrapper to a token that matches
+    nothing, and so is every doc-side pad, so no validity mask is needed.
     """
-    n_q, l_q = q_tok.shape
-    block_d, l_d = d_tok.shape
+    length, block_d = d_t_ref.shape
+    q_b = jnp.broadcast_to(q_col, (q_col.shape[0], block_d))
 
-    def fold(t, acc):
-        sub = jax.lax.dynamic_slice_in_dim(d_tok, t * tile_d, tile_d, axis=1)
-        eq = q_tok[:, :, None, None] == sub[None, None, :, :]
-        return acc + jnp.sum(eq, axis=-1, dtype=jnp.int32)
+    def add_rows(p0, n, acc):
+        for u in range(n):  # static unroll of one tile of positions
+            row = d_t_ref[pl.ds(p0 + u, 1), :]  # [1, block_d]
+            acc = acc + (q_b == row).astype(jnp.int32)
+        return acc
 
-    acc0 = jnp.zeros((n_q, l_q, block_d), jnp.int32)
-    tf = jax.lax.fori_loop(0, l_d // tile_d, fold, acc0)
-    return tf.astype(jnp.float32)
+    n_tiles = length // tile_d
+    acc = jnp.zeros(q_b.shape, jnp.int32)
+    acc = jax.lax.fori_loop(
+        0, n_tiles, lambda t, a: add_rows(t * tile_d, tile_d, a), acc
+    )
+    return add_rows(n_tiles * tile_d, length % tile_d, acc)
 
 
 def _lexical_scan_kernel(
-    q_ref,  # [n_q, L_q] int32 — resident (pads remapped to PAD_TOKEN - 1)
-    w_ref,  # [n_models, n_q, L_q] f32 — resident weight tables
-    ab_ref,  # [n_models, 2] f32 — resident (alpha, beta) per model
+    q_ref,  # [n_q * L_q, 1] int32 — resident query slots, (tile, term, row)
+    w_ref,  # [n_models, n_q / ROWS, L_q, ROWS, 1] f32 — resident weight tables
+    ab_ref,  # [n_models, 2] f32 in SMEM — (alpha, beta) per model
     d_ref,  # [block_d, L_d] int32 — or packed [block_d, W] when pack_spec
     dlen_ref,  # [1, block_d] int32 — this step's doc lengths
-    out_s_ref,  # [n_models, n_q, k] f32 — resident top-k scores
-    out_i_ref,  # [n_models, n_q, k] int32 — resident top-k ids
+    out_s_ref,  # [n_models, n_q, W] f32 — resident top-k scores
+    out_i_ref,  # [n_models, n_q, W] int32 — resident top-k ids
+    d_t_ref,  # [L_d, block_d] int32 VMEM scratch — the transposed tile
     *,
     modes: tuple[EpilogueMode, ...],
     block_d: int,
-    k: int,
     tile_d: int,
     pack_spec: packing.PackSpec | None = None,
-    l_dec: int = 0,
 ):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
-        out_s_ref[...] = jnp.full_like(out_s_ref, -jnp.inf)
-        out_i_ref[...] = jnp.full_like(out_i_ref, -1)
+        out_s_ref[...] = jnp.full(out_s_ref.shape, -jnp.inf, jnp.float32)
+        out_i_ref[...] = jnp.full(out_i_ref.shape, -1, jnp.int32)
 
-    q = q_ref[...]
     d = d_ref[...]
     if pack_spec is not None:
-        # decode the packed tile in VMEM right before the tf sub-tile loop:
-        # the stream tile stays `pack_spec.packed_width` wide in HBM and the
-        # int32 [block_d, L_d] view only ever exists on-chip. `l_dec` is the
-        # tile_d-aligned unpacked width (same PAD_TOKEN fill as the unpacked
-        # wrapper path), so the tf reduction below is identical either way.
-        d = packing.unpack_tokens(d, pack_spec, pad_to=l_dec)
-    dlen = dlen_ref[0, :]  # [block_d]
-    tf = _block_term_frequencies(q, d, tile_d=tile_d)  # shared by the grid
+        # decode the packed tile in VMEM: the stream tile stays
+        # `pack_spec.packed_width` wide in HBM and the int32 view only ever
+        # exists on-chip
+        d = packing.unpack_tokens(d, pack_spec)
+    d_t_ref[...] = d.T
+    dlen = dlen_ref[...]  # [1, block_d]
+    l_q = w_ref.shape[2]
+    slots = l_q * ROWS
 
-    k_pad = next_pow2(k)
-    cand_k = min(k, block_d)
-    for m, mode in enumerate(modes):  # n_models is static: unrolled epilogues
-        ep = LexicalEpilogue(w_ref[m], ab_ref[m, 0], ab_ref[m, 1])
-        s = apply_epilogue(mode, ep, tf, dlen)  # [n_q, block_d], VPU only
-        ids = step * block_d + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        cand_s, cand_pos = jax.lax.top_k(s, cand_k)  # sorted descending
-        cand_i = jnp.take_along_axis(ids, cand_pos, axis=1)
-        # zero-length rows score -inf; blank their ids so the merged state
-        # carries the host fold's (-inf, -1) empty-slot sentinel, never a
-        # padded corpus row
-        cand_i = jnp.where(cand_s == -jnp.inf, -1, cand_i)
-        cand_s, cand_i = _pad_desc(cand_s, cand_i, k_pad)
-        state_s, state_i = _pad_desc(out_s_ref[m], out_i_ref[m], k_pad)
-        top_s, top_i = bitonic_merge_desc(state_s, state_i, cand_s, cand_i)
-        out_s_ref[m] = top_s[:, :k]
-        out_i_ref[m] = top_i[:, :k]
+    def fold_rows(g, carry):
+        # one tile of ROWS queries: its L_q * ROWS slots are contiguous
+        q_col = q_ref[pl.ds(pl.multiple_of(g * slots, slots), slots), :]
+        tf = _block_term_frequencies(q_col, d_t_ref, tile_d=tile_d)
+        tf = tf.astype(jnp.float32).reshape(l_q, ROWS, block_d)
+        rows = pl.ds(pl.multiple_of(g * ROWS, ROWS), ROWS)
+        for m, mode in enumerate(modes):  # static: unrolled epilogues
+            s = epilogue_scores(mode, w_ref[m, g], ab_ref[m, 0], ab_ref[m, 1], tf, dlen)
+            ids = step * block_d + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            # zero-length rows score -inf; blank their ids so the merged
+            # state carries the host fold's (-inf, -1) empty-slot sentinel,
+            # never a padded corpus row
+            ids = jnp.where(s == -jnp.inf, -1, ids)
+            out_s_ref[m, rows, :], out_i_ref[m, rows, :] = fold_block(
+                out_s_ref[m, rows, :], out_i_ref[m, rows, :], s, ids
+            )
+        return carry
+
+    jax.lax.fori_loop(0, out_s_ref.shape[1] // ROWS, fold_rows, 0)
 
 
 def lexical_scan_topk_pallas(
@@ -143,9 +150,10 @@ def lexical_scan_topk_pallas(
 
     With ``pack_spec``, ``d_tokens`` is the packed matrix from
     `packing.pack_tokens` — the stream tile is ``pack_spec.packed_width``
-    columns instead of ``L_d`` (1/4 to 1/2 the HBM traffic) and each tile is
-    decoded in VMEM before the tf loop. The decode is exact, so results are
-    bit-identical to the unpacked call.
+    columns instead of ``L_d`` and each tile is decoded in VMEM before the
+    tf loop. The decode is exact, so results are bit-identical to the
+    unpacked call. Bit-plane packing decodes through lane-splitting reshapes
+    that Mosaic does not lower, so a compiled call refuses it.
     """
     n_q, l_q = q_tokens.shape
     n_d = d_tokens.shape[0]
@@ -154,46 +162,53 @@ def lexical_scan_topk_pallas(
         raise ValueError(f"{len(modes)} modes for {n_models} weight tables")
     if n_d % block_d:
         raise ValueError(f"{n_d} docs not divisible by block_d {block_d}")
-    # query pads -> a token that matches nothing (doc pads are PAD_TOKEN,
-    # real tokens >= 0), replacing the doc-side validity mask
-    q_safe = jnp.where(q_tokens == PAD_TOKEN, jnp.int32(PAD_TOKEN - 1), q_tokens)
+    length = d_tokens.shape[1]
     if pack_spec is not None:
-        if d_tokens.shape[1] != pack_spec.packed_width:
+        if length != pack_spec.packed_width:
             raise ValueError(
-                f"packed width {d_tokens.shape[1]} != spec {pack_spec.packed_width}"
+                f"packed width {length} != spec {pack_spec.packed_width}"
             )
-        l_d = d_tokens.shape[1]  # streamed width: the packed one
-        l_dec = pack_spec.length + (-pack_spec.length) % tile_d
-    else:
-        l_d = d_tokens.shape[1]
-        l_dec = 0
-        pad = (-l_d) % tile_d
-        if pad:
-            d_tokens = jnp.pad(
-                d_tokens, ((0, 0), (0, pad)), constant_values=PAD_TOKEN
+        if pack_spec.mode == "bitpack" and not interpret:
+            raise NotImplementedError(
+                "bit-plane packed tokens cannot be decoded in a compiled TPU "
+                "kernel; use token_pack 'none', '8' or '16' with use_kernel"
             )
-            l_d += pad
+        length = pack_spec.length
+    # query pads -> a token that matches nothing (doc pads are PAD_TOKEN or
+    # the pack sentinel, real tokens are in [0, vocab)); query rows padded
+    # to whole tiles of ROWS, and the slots of each tile laid out term-major
+    q_safe = jnp.where(q_tokens == PAD_TOKEN, jnp.int32(PAD_TOKEN - 1), q_tokens)
+    q_safe = pad_rows(q_safe, ROWS, PAD_TOKEN - 1)
+    n_rows = q_safe.shape[0]
+    tiles = n_rows // ROWS
+    q_col = q_safe.reshape(tiles, ROWS, l_q).transpose(0, 2, 1).reshape(-1, 1)
+    w_t = jnp.pad(weights, ((0, 0), (0, n_rows - n_q), (0, 0)))
+    w_t = w_t.reshape(n_models, tiles, ROWS, l_q).transpose(0, 1, 3, 2)[..., None]
+    width = state_width(k)
     kernel = functools.partial(
-        _lexical_scan_kernel, modes=modes, block_d=block_d, k=k, tile_d=tile_d,
-        pack_spec=pack_spec, l_dec=l_dec,
+        _lexical_scan_kernel, modes=modes, block_d=block_d, tile_d=tile_d,
+        pack_spec=pack_spec,
     )
-    return pl.pallas_call(
+    scores, ids = pl.pallas_call(
         kernel,
         grid=(n_d // block_d,),
         in_specs=[
-            pl.BlockSpec((n_q, l_q), lambda i: (0, 0)),  # Q resident in VMEM
-            pl.BlockSpec((n_models, n_q, l_q), lambda i: (0, 0, 0)),  # weights resident
-            pl.BlockSpec((n_models, 2), lambda i: (0, 0)),  # norm scalars resident
-            pl.BlockSpec((block_d, l_d), lambda i: (i, 0)),  # doc tokens streamed
+            pl.BlockSpec((n_rows * l_q, 1), lambda i: (0, 0)),  # Q resident
+            pl.BlockSpec(w_t.shape, lambda i: (0, 0, 0, 0, 0)),  # weights resident
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # norm scalars
+            pl.BlockSpec((block_d, d_tokens.shape[1]), lambda i: (i, 0)),  # streamed
             pl.BlockSpec((1, block_d), lambda i: (0, i)),  # doc lengths streamed
         ],
         out_specs=[
-            pl.BlockSpec((n_models, n_q, k), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_models, n_q, k), lambda i: (0, 0, 0)),
+            pl.BlockSpec((n_models, n_rows, width), lambda i: (0, 0, 0)),
+            pl.BlockSpec((n_models, n_rows, width), lambda i: (0, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_models, n_q, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_models, n_q, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_models, n_rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((n_models, n_rows, width), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((length, block_d), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(q_safe, weights, ab, d_tokens, d_len.reshape(1, n_d))
+    )(q_col, w_t, ab, d_tokens, d_len.reshape(1, n_d))
+    return scores[:, :n_q, :k], ids[:, :n_q, :k]

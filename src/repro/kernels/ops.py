@@ -66,24 +66,21 @@ def _interpret_default() -> bool:
     return kernel_backend() == "interpret"
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_d", "merge"))
-def _score_topk_jit(q, d, *, k: int, block_d: int, merge: str):
-    return score_topk_pallas(
-        q, d, k=k, block_d=block_d, merge=merge, interpret=_interpret_default()
-    )
+@functools.partial(jax.jit, static_argnames=("k", "block_d"))
+def _score_topk_jit(q, d, *, k: int, block_d: int):
+    return score_topk_pallas(q, d, k=k, block_d=block_d, interpret=_interpret_default())
 
 
-def score_topk(q, d, *, k: int, block_d: int | None = None, merge: str = "bitonic"):
+def score_topk(q, d, *, k: int, block_d: int | None = None):
     """Fused streaming score+top-k (MIREX map+combine). -> (scores, ids).
 
-    ``merge="bitonic"`` is the k-bounded combiner (O(k log k) per block);
-    ``merge="concat"`` is the legacy full re-sort, kept for parity checks.
+    The combiner is the k-bounded bitonic fold (`score_topk.fold_block`).
     ``block_d=None`` takes the active tuning's ``dense_block_d`` (1024 when
     untuned — the historical default).
     """
     if block_d is None:
         block_d = tune_config.active().config.dense_block_d or 1024
-    return _score_topk_jit(q, d, k=k, block_d=block_d, merge=merge)
+    return _score_topk_jit(q, d, k=k, block_d=block_d)
 
 
 @functools.partial(

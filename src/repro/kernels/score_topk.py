@@ -3,23 +3,31 @@
 The paper's hot loop scores every query against a stream of documents and
 keeps a running top-k. On TPU that is: stream document blocks HBM→VMEM, hit
 the MXU with a ``[n_q, dim] × [dim, block_d]`` tile, and fold the block's
-scores into a resident ``[n_q, k]`` top-k state — the full ``[n_q, n_d]``
+scores into a resident ``[n_q, W]`` top-k state — the full ``[n_q, n_d]``
 score matrix never exists, so HBM traffic is ``O(n_d · dim)`` instead of
 ``O(n_q · n_d)``. The TPU grid executes sequentially, which is exactly the
 combiner semantics: the output refs double as the running state.
 
-Combiner fold (``merge="bitonic"``, the default): the resident state is kept
-sorted descending, so folding a block only needs the block's own top-k
-(``lax.top_k`` over ``block_d``, sorted descending for free) merged against
-the state. Two sorted-k lists concatenated head-to-tail form a bitonic
-sequence, so a single O(k log k) bitonic *merge* network — ``log2(2k)``
-compare-exchange stages, each a reshape + elementwise max/min on the VPU —
-re-sorts them, instead of the legacy ``concatenate + top_k`` re-sort over
-``k + block_d`` candidates (``merge="concat"``, kept for parity testing).
+Combiner fold (:func:`fold_block`): the resident state is kept sorted by
+(score desc, id asc) at a power-of-two width ``W >= k`` (at least one lane
+tile, 128). Each block is bitonic-sorted *ascending* in VMEM, so the state
+and the block's best ``W`` form a bitonic sequence without any reversal; one
+half-cleaner keeps the better of each lane pair and ``log2(W)`` more
+compare-exchange stages re-sort it. Every stage is a lane permutation —
+``pltpu.roll`` by the stride plus a select on ``lane & stride`` — and a
+lexicographic compare on the VPU: no ``top_k``, no gathers, no lane-splitting
+reshapes, so Mosaic lowers it at every stride. The same network, with XLA's
+lowering of the roll, is the cross-shard reduce (`topk.merge_lex`), so the
+kernel combiner and the cluster merge share one ordering contract.
 
-BlockSpecs: Q ``(n_q, dim)`` resident across steps; D ``(block_d, dim)``
-streamed; outputs ``(n_q, k)`` pinned to block (0, 0). MXU alignment wants
-``n_q % 8 == 0``, ``dim % 128 == 0``, ``block_d % 128 == 0``.
+The fold walks the query rows ``ROWS`` (one sublane tile) at a time, so a
+step's sort and merge stay in vector registers and compile once, not once
+per row tile.
+
+BlockSpecs: Q ``(n_q, dim)`` resident across steps (padded to whole row
+tiles); D ``(block_d, dim)`` streamed; outputs ``(n_q, W)`` pinned to block
+(0, 0) and cut to ``(n_q, k)`` by the wrapper. Compiled alignment wants
+``dim % 128 == 0`` and ``block_d % 128 == 0``.
 """
 
 from __future__ import annotations
@@ -29,8 +37,81 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pipeline import next_pow2
+
+LANES = 128  # one vreg row: the narrowest width the compiled network runs at
+ROWS = 8  # query rows folded per loop step: one sublane tile, held in vregs
+
+
+def state_width(k: int) -> int:
+    """Lane width of the resident top-k state: a power of two, >= k, >= 128."""
+    return max(next_pow2(k), LANES)
+
+
+def _better(s, i, ps, pi):
+    """(s, i) ranks ahead of (ps, pi) under (score desc, id asc)."""
+    return (s > ps) | ((s == ps) & (i < pi))
+
+
+def _exchange(s, i, stride: int, take_max, roll):
+    """One compare-exchange stage along the last axis.
+
+    Lane ``j`` meets lane ``j ^ stride``: a roll by ``stride`` brings the
+    lower partner to the upper lane, a roll by ``n - stride`` the upper
+    partner to the lower lane, and ``lane & stride`` picks which one applies.
+    ``take_max`` marks the lanes that keep the better entry of their pair.
+    ``roll`` is ``pltpu.roll`` inside kernels and ``jnp.roll`` outside (the
+    two agree; only the first lowers to Mosaic, only the second runs eagerly).
+    """
+    n = s.shape[-1]
+    axis = s.ndim - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+    upper = (lane & stride) != 0
+    ps = jnp.where(upper, roll(s, stride, axis), roll(s, n - stride, axis))
+    pi = jnp.where(upper, roll(i, stride, axis), roll(i, n - stride, axis))
+    keep = _better(s, i, ps, pi) == take_max
+    return jnp.where(keep, s, ps), jnp.where(keep, i, pi)
+
+
+def bitonic_sort(s, i, *, descending: bool, roll=pltpu.roll):
+    """Sort ``[..., n]`` (score, id) pairs along the last axis by (score
+    desc, id asc), or its exact reverse; ``n`` must be a power of two."""
+    n = s.shape[-1]
+    assert n & (n - 1) == 0, f"bitonic sort needs power-of-two width, got {n}"
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
+    size = 2
+    while size <= n:
+        # runs of `size` alternate direction, so each pair of runs is bitonic
+        # for the next phase; the last phase (size == n) runs one direction
+        run_desc = ((lane & size) == 0) == descending
+        stride = size // 2
+        while stride:
+            s, i = _exchange(s, i, stride, ((lane & stride) == 0) == run_desc, roll)
+            stride //= 2
+        size *= 2
+    return s, i
+
+
+def bitonic_top(a_s, a_i, b_s, b_i, *, roll=pltpu.roll):
+    """Top ``m`` of two ``[..., m]`` lists, ``a`` sorted (score desc, id asc)
+    and ``b`` sorted the exact reverse way; ``m`` a power of two.
+
+    ``a ++ b`` is bitonic, so its half-cleaner (lane ``j`` against lane
+    ``j + m``, i.e. ``a[j]`` against ``b[j]``) leaves the ``m`` best entries,
+    themselves bitonic, in the lower half; ``log2(m)`` stages sort them.
+    """
+    m = a_s.shape[-1]
+    assert m & (m - 1) == 0, f"bitonic merge needs power-of-two width, got {m}"
+    keep = _better(a_s, a_i, b_s, b_i)
+    s, i = jnp.where(keep, a_s, b_s), jnp.where(keep, a_i, b_i)
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
+    stride = m // 2
+    while stride:
+        s, i = _exchange(s, i, stride, (lane & stride) == 0, roll)
+        stride //= 2
+    return s, i
 
 
 def bitonic_merge_desc(
@@ -39,92 +120,83 @@ def bitonic_merge_desc(
     """Merge two ``[..., m]`` (score, id) lists sorted by (score desc, id
     asc); keep the top m under that same lexicographic order.
 
-    ``a ++ reverse(b)`` is bitonic (descending then ascending), so one
-    bitonic merge network — ``log2(2m) `` compare-exchange stages expressed
-    as reshapes + ``where`` (VPU-friendly: no gathers) — yields the 2m
-    values fully sorted; the first m are the merged top-m. ``m`` must be a
-    power of two (pad with ``-inf``/``-1`` first).
-
     Ties break toward the **smaller id**. Scan candidates carry strictly
     increasing doc ids across stream blocks, so this is exactly
-    ``lax.top_k``'s positional tie-break on the host fold
-    (`topk.update`) — what keeps kernel and host rankings id-exact even on
-    the equal scores lexical scoring mass-produces (e.g. every
-    zero-match document under BM25).
+    ``lax.top_k``'s positional tie-break on the host fold (`topk.update`) —
+    what keeps kernel and host rankings id-exact even on the equal scores
+    lexical scoring mass-produces (e.g. every zero-match document under
+    BM25). The order is total on distinct ids, so the output is a pure
+    function of the two value sets. ``m`` must be a power of two (pad with
+    ``-inf``/``-1`` first). Outside kernels only: the reverse of ``b`` is an
+    XLA ``rev``.
     """
-    m = a_s.shape[-1]
-    assert m & (m - 1) == 0, f"bitonic merge needs power-of-two width, got {m}"
-    lead = a_s.shape[:-1]
-    s = jnp.concatenate([a_s, b_s[..., ::-1]], axis=-1)
-    i = jnp.concatenate([a_i, b_i[..., ::-1]], axis=-1)
-    length = 2 * m
-    stride = m
-    while stride >= 1:
-        sr = s.reshape(*lead, length // (2 * stride), 2, stride)
-        ir = i.reshape(*lead, length // (2 * stride), 2, stride)
-        lo_s, hi_s = sr[..., 0, :], sr[..., 1, :]
-        lo_i, hi_i = ir[..., 0, :], ir[..., 1, :]
-        # descending by score, ascending by id on ties: max to lower position
-        keep = (lo_s > hi_s) | ((lo_s == hi_s) & (lo_i <= hi_i))
-        max_s = jnp.where(keep, lo_s, hi_s)
-        min_s = jnp.where(keep, hi_s, lo_s)
-        max_i = jnp.where(keep, lo_i, hi_i)
-        min_i = jnp.where(keep, hi_i, lo_i)
-        s = jnp.stack([max_s, min_s], axis=-2).reshape(*lead, length)
-        i = jnp.stack([max_i, min_i], axis=-2).reshape(*lead, length)
-        stride //= 2
-    return s[..., :m], i[..., :m]
+    return bitonic_top(a_s, a_i, b_s[..., ::-1], b_i[..., ::-1], roll=jnp.roll)
 
 
-def _pad_desc(s: jax.Array, i: jax.Array, width: int) -> tuple[jax.Array, jax.Array]:
-    """Right-pad descending-sorted lists with (-inf, -1) sentinels."""
+def pad_lanes(s, i, width: int, *, front: bool = False):
+    """Pad the last axis to ``width`` with ``(-inf, -1)`` empty slots."""
     pad = width - s.shape[-1]
     if pad == 0:
         return s, i
-    widths = [(0, 0)] * (s.ndim - 1) + [(0, pad)]
-    return (
-        jnp.pad(s, widths, constant_values=-jnp.inf),
-        jnp.pad(i, widths, constant_values=-1),
-    )
+    fill_s = jnp.full((*s.shape[:-1], pad), -jnp.inf, s.dtype)
+    fill_i = jnp.full((*i.shape[:-1], pad), -1, i.dtype)
+    if front:
+        return (jnp.concatenate([fill_s, s], axis=-1),
+                jnp.concatenate([fill_i, i], axis=-1))
+    return (jnp.concatenate([s, fill_s], axis=-1),
+            jnp.concatenate([i, fill_i], axis=-1))
 
 
-def _score_topk_kernel(
-    q_ref, d_ref, out_s_ref, out_i_ref, *, block_d: int, k: int, merge: str
-):
+def fold_block(state_s, state_i, s, ids):
+    """Fold one ``[rows, block_d]`` block of candidates into a ``[rows, W]``
+    state sorted by (score desc, id asc); returns the new state.
+
+    The block is sorted ascending at a power-of-two width of at least one
+    lane tile; its best ``W`` (the last lanes) then meet the state in
+    :func:`bitonic_top`. Empty slots are ``(-inf, -1)``.
+    """
+    width = state_s.shape[-1]
+    bp = max(next_pow2(s.shape[-1]), LANES)
+    s, ids = pad_lanes(s, ids, bp)
+    s, ids = bitonic_sort(s, ids, descending=False)
+    if bp > width:
+        s, ids = s[:, bp - width:], ids[:, bp - width:]
+    else:
+        s, ids = pad_lanes(s, ids, width, front=True)
+    return bitonic_top(state_s, state_i, s, ids)
+
+
+def _score_topk_kernel(q_ref, d_ref, out_s_ref, out_i_ref, s_ref, *, block_d: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
-        out_s_ref[...] = jnp.full_like(out_s_ref, -jnp.inf)
-        out_i_ref[...] = jnp.full_like(out_i_ref, -1)
+        out_s_ref[...] = jnp.full(out_s_ref.shape, -jnp.inf, jnp.float32)
+        out_i_ref[...] = jnp.full(out_i_ref.shape, -1, jnp.int32)
 
-    q = q_ref[...]  # [n_q, dim] — resident
-    d = d_ref[...]  # [block_d, dim] — this step's stream block
-    s = jax.lax.dot_general(
-        q, d, dimension_numbers=(((1,), (1,)), ((), ())),
+    s_ref[...] = jax.lax.dot_general(
+        q_ref[...], d_ref[...], dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [n_q, block_d] on the MXU
-    ids = step * block_d + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
 
-    if merge == "concat":
-        # legacy combiner: re-sort all k + block_d candidates every step
-        cat_s = jnp.concatenate([out_s_ref[...], s], axis=1)
-        cat_i = jnp.concatenate([out_i_ref[...], ids], axis=1)
-        top_s, pos = jax.lax.top_k(cat_s, k)
-        out_s_ref[...] = top_s
-        out_i_ref[...] = jnp.take_along_axis(cat_i, pos, axis=1)
-        return
+    def fold_rows(g, carry):
+        rows = pl.ds(pl.multiple_of(g * ROWS, ROWS), ROWS)
+        s = s_ref[rows, :]
+        ids = step * block_d + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        out_s_ref[rows, :], out_i_ref[rows, :] = fold_block(
+            out_s_ref[rows, :], out_i_ref[rows, :], s, ids
+        )
+        return carry
 
-    # k-bounded combiner: only the block's top-k ever meets the state
-    k_pad = next_pow2(k)
-    cand_k = min(k, block_d)
-    cand_s, cand_pos = jax.lax.top_k(s, cand_k)  # sorted descending
-    cand_i = jnp.take_along_axis(ids, cand_pos, axis=1)
-    cand_s, cand_i = _pad_desc(cand_s, cand_i, k_pad)
-    state_s, state_i = _pad_desc(out_s_ref[...], out_i_ref[...], k_pad)
-    top_s, top_i = bitonic_merge_desc(state_s, state_i, cand_s, cand_i)
-    out_s_ref[...] = top_s[:, :k]
-    out_i_ref[...] = top_i[:, :k]
+    jax.lax.fori_loop(0, s_ref.shape[0] // ROWS, fold_rows, 0)
+
+
+def pad_rows(x, multiple: int = 8, value=0):
+    """Pad the leading axis up to a multiple of ``multiple`` rows."""
+    pad = -x.shape[0] % multiple
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1), constant_values=value)
 
 
 def score_topk_pallas(
@@ -134,28 +206,31 @@ def score_topk_pallas(
     k: int,
     block_d: int = 1024,
     interpret: bool = True,
-    merge: str = "bitonic",
 ) -> tuple[jax.Array, jax.Array]:
-    if merge not in ("bitonic", "concat"):
-        raise ValueError(f"unknown merge {merge!r}; expected 'bitonic' or 'concat'")
     n_q, dim = q.shape
     n_d, _ = d.shape
     assert n_d % block_d == 0, (n_d, block_d)
-    kernel = functools.partial(_score_topk_kernel, block_d=block_d, k=k, merge=merge)
-    return pl.pallas_call(
+    q = pad_rows(q, ROWS)
+    n_rows = q.shape[0]
+    width = state_width(k)
+    kernel = functools.partial(_score_topk_kernel, block_d=block_d)
+    scores, ids = pl.pallas_call(
         kernel,
         grid=(n_d // block_d,),
         in_specs=[
-            pl.BlockSpec((n_q, dim), lambda i: (0, 0)),  # Q resident in VMEM
+            pl.BlockSpec((n_rows, dim), lambda i: (0, 0)),  # Q resident in VMEM
             pl.BlockSpec((block_d, dim), lambda i: (i, 0)),  # D streamed
         ],
         out_specs=[
-            pl.BlockSpec((n_q, k), lambda i: (0, 0)),
-            pl.BlockSpec((n_q, k), lambda i: (0, 0)),
+            pl.BlockSpec((n_rows, width), lambda i: (0, 0)),
+            pl.BlockSpec((n_rows, width), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_q, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_q, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((n_rows, width), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((n_rows, block_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(q, d)
+    return scores[:n_q, :k], ids[:n_q, :k]

@@ -36,6 +36,7 @@ from repro.core import scoring
 from repro.experiments import bench as exp_bench
 from repro.experiments import grid as exp_grid
 from repro.experiments import runner
+from repro.launch.cache import use_compile_cache
 
 
 def _spec_from_args(args) -> exp_grid.ExperimentSpec:
@@ -206,6 +207,7 @@ def main():
     ap.add_argument("--bench-sizes", type=int, nargs="+", default=[1, 2, 4, 8])
     ap.add_argument("--bench-out", default="BENCH_experiments.json")
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = _spec_from_args(args)
     out_dir = args.out if args.experiment is None else f"{args.out}/{spec.name}"

@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import set_mesh  # noqa: F401  (re-export + installs jax.set_mesh shim)
+from repro.compat import set_mesh  # noqa: F401  (re-export)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

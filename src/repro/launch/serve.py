@@ -27,6 +27,7 @@ from repro.core import anchors
 from repro.data import synthetic
 from repro.obs import Metrics
 from repro.distributed.sharding import rules_for_mesh
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_test_mesh, set_mesh
 from repro.models import transformer as tfm
 from repro.serve import (
@@ -198,6 +199,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--arch", default="gemma2-2b")
     args = ap.parse_args()
+    use_compile_cache()
     if args.mode == "search":
         serve_search(
             args.n_queries,

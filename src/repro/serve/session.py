@@ -29,25 +29,38 @@ def _pack_resident(tokens, lengths, *, vocab: int | None, mode: str | None):
     """Resolve the resident corpus representation for a lexical session.
 
     ``mode=None`` follows the active tuning's ``token_pack`` knob. Returns
-    the plain int32 ``(tokens, lengths)`` tuple, or a ``PackedCorpus``
-    whose device arrays hold the narrow representation — resident HBM drops
-    by the pack ratio, so bigger corpora fit resident, and the scan decodes
-    per chunk/tile with bit-identical results. Packing needs the vocab
-    (for the sentinel); without one we stay unpacked rather than fail.
+    host arrays — the plain int32 ``(tokens, lengths)`` tuple, or a
+    ``PackedCorpus`` holding the narrow representation — which the session
+    then places on its device(s): resident HBM drops by the pack ratio, so
+    bigger corpora fit resident, and the scan decodes per chunk/tile with
+    bit-identical results. Packing needs the vocab (for the sentinel);
+    without one we stay unpacked rather than fail.
     """
     if mode is None:
         mode = tune_config.active().config.token_pack
-    t32 = jnp.asarray(tokens, jnp.int32)
-    l32 = jnp.asarray(lengths, jnp.int32)
+    t32 = np.asarray(tokens, np.int32)
+    l32 = np.asarray(lengths, np.int32)
     if mode == "none" or vocab is None:
         return (t32, l32)
-    packed = packing.pack_corpus(
-        np.asarray(tokens, np.int32), np.asarray(lengths, np.int32),
-        vocab=vocab, mode=mode,
+    return packing.pack_corpus(t32, l32, vocab=vocab, mode=mode)
+
+
+def _sharded_stats(mesh, axis_names, docs, *, vocab: int, chunk_size: int):
+    """The statistics job on a shard-resident corpus: each device folds its
+    own rows and the additive states ``psum`` across the scan axes, so no
+    device ever holds the whole corpus. Integer sums, so the result equals
+    the single-host job's bit for bit."""
+    spec = P(axis_names)
+
+    def local(tokens, lengths):
+        return anchors.collection_stats(
+            tokens, lengths, vocab=vocab, chunk_size=chunk_size, axis_name=axis_names
+        )
+
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec), out_specs=P(), check_vma=False
     )
-    if not isinstance(packed, packing.PackedCorpus):
-        return (t32, l32)
-    return jax.tree.map(jnp.asarray, packed)
+    return jax.jit(fn)(*docs)
 
 
 class LexicalSession:
@@ -105,13 +118,17 @@ class LexicalSession:
         # vocab; derive it from the stats' cf table when not passed.
         if vocab is None:
             vocab = int(self._stats.cf.shape[0])
-        self._docs = _pack_resident(tokens, lengths, vocab=vocab, mode=token_pack)
+        self._docs = jax.tree.map(
+            jnp.asarray, _pack_resident(tokens, lengths, vocab=vocab, mode=token_pack)
+        )
 
         scorer_, k_, chunk_ = self.scorer, k, chunk_size
-        docs, st = self._docs, self._stats
 
+        # the corpus and stats are arguments: closed over, they would be
+        # compiled into the executable as constants (a second device copy,
+        # minutes of compile at full width, a program too big to cache)
         @jax.jit
-        def _handle(q):
+        def _scan(q, docs, st):
             # resolved at trace time: set_kernel_backend clears jit caches,
             # so a backend flip re-resolves on the next call (ops.py contract)
             kern = use_kernel
@@ -123,7 +140,7 @@ class LexicalSession:
                 q, docs, scorer_, k=k_, chunk_size=chunk_, stats=st, use_kernel=kern
             )
 
-        self._handle = _handle
+        self._scan = _scan
 
     @property
     def n_docs(self) -> int:
@@ -143,7 +160,8 @@ class LexicalSession:
 
     def search(self, q_block: np.ndarray) -> topk.TopKState:
         """Scan one padded query block; blocks until results are on host."""
-        return jax.block_until_ready(self._handle(jnp.asarray(q_block, jnp.int32)))
+        q = jnp.asarray(q_block, jnp.int32)
+        return jax.block_until_ready(self._scan(q, self._docs, self._stats))
 
 
 class ShardedLexicalSession:
@@ -211,22 +229,26 @@ class ShardedLexicalSession:
         )
         doc_sharding = NamedSharding(mesh, P(axis_names))
         repl = NamedSharding(mesh, P())
+        if stats is None and vocab is None:
+            raise ValueError("need stats or vocab to derive collection statistics")
+        if vocab is None:
+            vocab = int(np.shape(stats.cf)[0])
+
+        def place(tree):
+            # host -> each device's own shard: both corpus leaves (packed or
+            # not) share the doc leading dim, so one PartitionSpec places
+            # either representation, and no device stages the whole corpus
+            return jax.tree.map(lambda x: jax.device_put(x, doc_sharding), tree)
+
+        self._docs = place(_pack_resident(tokens, lengths, vocab=vocab, mode=token_pack))
         if stats is None:
-            if vocab is None:
-                raise ValueError("need stats or vocab to derive collection statistics")
-            stats = anchors.collection_stats(
-                jnp.asarray(tokens, jnp.int32), jnp.asarray(lengths, jnp.int32),
-                vocab=vocab, chunk_size=chunk_size,
+            raw_docs = self._docs
+            if isinstance(raw_docs, packing.PackedCorpus):
+                raw_docs = place(_pack_resident(tokens, lengths, vocab=vocab, mode="none"))
+            stats = _sharded_stats(
+                mesh, axis_names, raw_docs, vocab=vocab, chunk_size=chunk_size
             )
         self._stats = jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), repl), stats)
-        if vocab is None:
-            vocab = int(self._stats.cf.shape[0])
-        # both corpus leaves (packed or not) share the doc leading dim, so
-        # one PartitionSpec places either representation shard-resident
-        self._docs = jax.tree.map(
-            lambda x: jax.device_put(x, doc_sharding),
-            _pack_resident(tokens, lengths, vocab=vocab, mode=token_pack),
-        )
         self._lengths = (
             self._docs.lengths
             if isinstance(self._docs, packing.PackedCorpus)
@@ -303,15 +325,14 @@ class DenseSession:
             )
 
         scorer_, k_, chunk_, kern = self.scorer, k, chunk_size, use_kernel
-        vecs = self._vectors
 
         @jax.jit
-        def _handle(q):
+        def _scan(q, vecs):  # vectors as an argument, as in LexicalSession
             return scan.search_local(
                 q, vecs, scorer_, k=k_, chunk_size=chunk_, use_kernel=kern
             )
 
-        self._handle = _handle
+        self._scan = _scan
 
     @property
     def n_docs(self) -> int:
@@ -322,4 +343,5 @@ class DenseSession:
         return int(self._vectors.shape[1])
 
     def search(self, q_block: np.ndarray) -> topk.TopKState:
-        return jax.block_until_ready(self._handle(jnp.asarray(q_block, jnp.float32)))
+        q = jnp.asarray(q_block, jnp.float32)
+        return jax.block_until_ready(self._scan(q, self._vectors))

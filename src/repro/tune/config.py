@@ -181,22 +181,19 @@ class TuningConfig:
     def lex_block(self, chunk_size: int, n_rows: int | None = None) -> int:
         """Lexical-kernel doc tile for a scan over ``chunk_size`` chunks.
 
-        ``None`` follows the chunk (today's behavior); an explicit block
-        that doesn't divide the shard gracefully falls back to the chunk —
-        the scan must never fail on a knob, only ignore it (byte-identical
-        either way: block size only regroups the combiner fold).
+        ``None`` follows the chunk, halved while it exceeds 512 rows (the
+        largest tile whose tf block and sort fit the kernel's scoped VMEM on
+        a v5e); an explicit block that doesn't divide the shard gracefully
+        falls back to that default — the scan must never fail on a knob,
+        only ignore it (byte-identical either way: block size only regroups
+        the combiner fold).
         """
-        block = self.lex_block_d if self.lex_block_d is not None else chunk_size
-        if n_rows is not None and n_rows % block:
-            block = chunk_size
-        return block
+        return _block(self.lex_block_d, chunk_size, n_rows, cap=512)
 
     def dense_block(self, chunk_size: int, n_rows: int | None = None) -> int:
-        """Dense-kernel doc tile; same rules as :meth:`lex_block`."""
-        block = self.dense_block_d if self.dense_block_d is not None else chunk_size
-        if n_rows is not None and n_rows % block:
-            block = chunk_size
-        return block
+        """Dense-kernel doc tile; same rules as :meth:`lex_block`, capped at
+        1024 rows."""
+        return _block(self.dense_block_d, chunk_size, n_rows, cap=1024)
 
     def fold_key(self, use_kernel: bool) -> tuple:
         """The knobs that shape the *compiled* fold program — the tuning
@@ -208,6 +205,18 @@ class TuningConfig:
         if not use_kernel:
             return ()
         return (self.lex_block_d, self.lex_tile_d, self.dense_block_d)
+
+
+def _block(knob: int | None, chunk_size: int, n_rows: int | None, *, cap: int) -> int:
+    """Kernel doc tile: the knob where it divides the shard, else the chunk
+    halved (while even) down to ``cap`` rows."""
+    default = chunk_size
+    while default > cap and default % 2 == 0:
+        default //= 2
+    block = knob if knob is not None else default
+    if n_rows is not None and n_rows % block:
+        block = default
+    return block
 
 
 DEFAULT = TuningConfig()

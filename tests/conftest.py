@@ -2,8 +2,6 @@ import jax
 import numpy as np
 import pytest
 
-import repro.compat  # noqa: F401  — installs jax.set_mesh fallback on older JAX
-
 # NOTE: no XLA_FLAGS here on purpose — tests must see the real single CPU
 # device (the 512-device override belongs to launch/dryrun.py only).
 

@@ -286,3 +286,44 @@ def test_experiment_pipelined_flag_round_trips(tmp_path):
             == open(r_pipe["runs"][name], "rb").read()
         ), name
     assert r_seq["metrics"] == r_pipe["metrics"]
+
+
+def test_stager_claim_never_races_its_staging_thread():
+    """A worker may claim a shard the instant another worker publishes its
+    staging thread: `take` must always find a started thread to join."""
+    import sys
+    import threading
+
+    from repro.cluster.job import _ShardStager
+
+    n_shards = 32
+    docs = (np.zeros((n_shards * 8, 4), np.int32), np.zeros(n_shards * 8, np.int32))
+    plan = cluster.plan_shards(n_shards * 8, n_shards=n_shards, chunk_size=8)
+    device = jax.devices()[0]
+    errors = []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for _ in range(20):
+            stager = _ShardStager(docs, plan, [device], seg_rows=8)
+
+            def stage():
+                for _ in range(n_shards):
+                    stager.stage_next()
+
+            def claim():
+                try:
+                    for i in range(n_shards):
+                        stager.take(i, device)
+                except RuntimeError as e:  # joined before it was started
+                    errors.append(e)
+
+            workers = [threading.Thread(target=f) for f in (stage, claim, stage)]
+            for t in workers:
+                t.start()
+            for t in workers:
+                t.join(timeout=30)
+                assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    assert errors == []

@@ -1,14 +1,18 @@
 """Serve subsystem: microbatch triggers + padding, dispatch parity against
 the scan engine oracles, and the k-bounded bitonic kernel merge."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
-from repro.core import anchors, scan, scoring
+from repro.core import anchors, scan, scoring, topk
 from repro.data import synthetic
 from repro.kernels import ops
-from repro.kernels.score_topk import bitonic_merge_desc
+from repro.kernels.score_topk import bitonic_merge_desc, fold_block, state_width
 from repro.serve import DenseSession, LexicalSession, Microbatcher, RetrievalService
 from repro.serve.microbatch import bucket_size, pad_rows, unpad_results
 
@@ -146,6 +150,24 @@ def test_every_query_answered_exactly_once_across_waves(rng):
     assert all(len(r.scores) == 5 for r in answered.values())
 
 
+@pytest.mark.parametrize("kind", ["lexical", "dense"])
+def test_session_corpus_is_an_argument_not_a_constant(rng, kind):
+    """The resident corpus enters the jitted scan as an argument. Closed
+    over, it would be compiled into the executable as a constant: a second
+    device copy, and at full width minutes of compile and a program too big
+    for the persistent compile cache."""
+    if kind == "lexical":
+        corpus, _, session = _lexical_fixture()
+        queries = synthetic.make_queries(corpus, n_queries=8, seed=3)
+        args = (jnp.asarray(queries), session._docs, session._stats)
+    else:
+        vecs = rng.standard_normal((512, 64)).astype(np.float32)
+        session = DenseSession(vecs, k=9, chunk_size=128, use_kernel=False)
+        args = (jnp.zeros((8, 64), jnp.float32), session._vectors)
+    consts = session._scan.trace(*args).jaxpr.consts
+    assert sum(np.size(c) for c in consts) < 1024
+
+
 # -------------------------------------------------------- k-bounded merge
 
 
@@ -169,21 +191,75 @@ def test_bitonic_merge_desc_matches_numpy(rng):
         )
 
 
-@pytest.mark.parametrize("k", [5, 16, 100])
+@pytest.mark.parametrize("k", [5, 16, 100, 300])
 def test_kernel_bitonic_merge_matches_host_oracle(rng, k):
-    """Acceptance: exact ids on distinct scores, scores within 1e-5."""
+    """Acceptance: exact ids on distinct scores, scores within 1e-5 (k=300
+    exceeds the 128-doc block)."""
     q = jnp.asarray(rng.standard_normal((8, 128)), jnp.float32)
     d = jnp.asarray(rng.standard_normal((1024, 128)), jnp.float32)
-    s, i = ops.score_topk(q, d, k=k, block_d=128, merge="bitonic")
+    s, i = ops.score_topk(q, d, k=k, block_d=128)
     ref = scan.search_dense_host(q, d, k=k)
     np.testing.assert_allclose(np.asarray(s), np.asarray(ref.scores), rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ref.ids))
 
 
-def test_kernel_bitonic_equals_legacy_concat_merge(rng):
-    q = jnp.asarray(rng.standard_normal((16, 64)), jnp.float32)
-    d = jnp.asarray(rng.standard_normal((512, 64)), jnp.float32)
-    s1, i1 = ops.score_topk(q, d, k=12, block_d=64, merge="bitonic")
-    s2, i2 = ops.score_topk(q, d, k=12, block_d=64, merge="concat")
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2))
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+
+# exactly representable scores: every draw is riddled with ties, so the id
+# tie-break decides most of the ranking
+TIED_SCORES = np.array([-2.0, -0.5, 0.0, 0.25, 0.5, 1.0, 1.5, 2.0], np.float32)
+
+
+def _fold_blocks_kernel(s_ref, out_s_ref, out_i_ref, *, block_d):
+    step = pl.program_id(0)
+
+    @pl.when(step == 0)
+    def _init():
+        out_s_ref[...] = jnp.full(out_s_ref.shape, -jnp.inf, jnp.float32)
+        out_i_ref[...] = jnp.full(out_i_ref.shape, -1, jnp.int32)
+
+    s = s_ref[...]
+    ids = step * block_d + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    out_s_ref[...], out_i_ref[...] = fold_block(out_s_ref[...], out_i_ref[...], s, ids)
+
+
+def _lex_top(scores, ids, k):
+    """Per row: top k by (score desc, id asc), padded with (-inf, -1)."""
+    rows = scores.shape[0]
+    out_s = np.full((rows, k), -np.inf, np.float32)
+    out_i = np.full((rows, k), -1, np.int32)
+    for r in range(rows):
+        order = np.lexsort((ids[r], -scores[r]))[:k]
+        out_s[r, : order.size] = scores[r, order]
+        out_i[r, : order.size] = ids[r, order]
+    return topk.TopKState(scores=jnp.asarray(out_s), ids=jnp.asarray(out_i))
+
+
+@pytest.mark.parametrize("k,block_d", [(5, 64), (12, 128), (100, 64), (300, 128)])
+def test_kernel_fold_network_matches_merge_lex(rng, k, block_d):
+    """The in-kernel merge network (the roll-based bitonic sort and
+    half-cleaner, interpret mode) folds tie-riddled blocks to the same bytes
+    as `topk.merge_lex` over each block's own top-k: k not a power of two,
+    and k larger than the block."""
+    rows, n_blocks = 8, 5
+    scores = rng.choice(TIED_SCORES, size=(rows, n_blocks * block_d))
+    width = state_width(k)
+    got_s, got_i = pl.pallas_call(
+        functools.partial(_fold_blocks_kernel, block_d=block_d),
+        grid=(n_blocks,),
+        in_specs=[pl.BlockSpec((rows, block_d), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((rows, width), lambda i: (0, 0))] * 2,
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((rows, width), jnp.int32),
+        ],
+        interpret=True,
+    )(jnp.asarray(scores))
+
+    ids = np.broadcast_to(np.arange(n_blocks * block_d, dtype=np.int32), scores.shape)
+    want = None
+    for b in range(n_blocks):
+        cols = slice(b * block_d, (b + 1) * block_d)
+        block = _lex_top(scores[:, cols], ids[:, cols], k)
+        want = block if want is None else topk.merge_lex(want, block)
+    np.testing.assert_array_equal(np.asarray(got_i)[:, :k], np.asarray(want.ids))
+    assert np.asarray(got_s)[:, :k].tobytes() == np.asarray(want.scores).tobytes()
