@@ -37,12 +37,22 @@ from repro import obs
 _VIEW_AS = {"bfloat16": np.uint16, "float8_e4m3fn": np.uint8, "float8_e5m2": np.uint8}
 
 
+# the AsyncCheckpointer whose writer thread is running the current task
+_WRITER = threading.local()
+
+
+def _writer_backlog() -> int:
+    """Tasks waiting behind the one running, on a writer thread (else 0)."""
+    writer = getattr(_WRITER, "checkpointer", None)
+    return 0 if writer is None else writer._queue.qsize()
+
+
 def _flatten(tree):
     leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
     return [(jax.tree_util.keystr(path), leaf) for path, leaf in leaves], treedef
 
 
-def save(ckpt_dir: str, step: int, tree, *, on_commit=None) -> str:
+def save(ckpt_dir: str, step: int, tree, *, on_commit=None, shard: int = 0) -> str:
     """Write checkpoint for ``step``; returns the final directory.
 
     ``on_commit(step, tmp_dir)``, if given, runs after the full write but
@@ -51,42 +61,46 @@ def save(ckpt_dir: str, step: int, tree, *, on_commit=None) -> str:
     failure at that instant would leave). This is the checkpoint-writer
     fault-injection point used by ``cluster.faults``; a later retry of the
     same step removes the stale tmp dir and commits cleanly.
+
+    ``shard`` only labels the spans: ``ckpt.fetch`` (the state's leaves to
+    the host: for a scan job's segment the first wait on its fold) and
+    ``ckpt.write`` (the ``.npy`` files and the manifest), both inside
+    ``ckpt.save``.
     """
     final = os.path.join(ckpt_dir, f"step_{step:08d}")
     tmp = os.path.join(ckpt_dir, f".tmp-step_{step:08d}")
+    tr = obs.tracer()
     t_save = time.monotonic()
-    with obs.tracer().span("ckpt.save", "ckpt", step=step):
+    with tr.span("ckpt.save", "ckpt", shard=shard, step=step):
         if os.path.exists(tmp):
             shutil.rmtree(tmp)
         os.makedirs(tmp, exist_ok=True)
         named, _ = _flatten(tree)
-        manifest = []
-        written = 0
-        for i, (key, leaf) in enumerate(named):
-            arr = np.asarray(jax.device_get(leaf))
-            true_dtype = str(arr.dtype)
-            if true_dtype in _VIEW_AS:
-                arr = arr.view(_VIEW_AS[true_dtype])
-            fname = f"leaf_{i:05d}.npy"
-            np.save(os.path.join(tmp, fname), arr)
-            written += arr.nbytes
-            manifest.append({"key": key, "file": fname, "shape": list(arr.shape), "dtype": true_dtype})
-        with open(os.path.join(tmp, "manifest.json"), "w") as f:
-            json.dump({"step": step, "leaves": manifest}, f)
+        with tr.span("ckpt.fetch", "ckpt", shard=shard, step=step) as fetch:
+            if tr.enabled:
+                fetch.set(queued=_writer_backlog())
+            arrays = jax.device_get([leaf for _, leaf in named])
+        with tr.span("ckpt.write", "ckpt", shard=shard, step=step):
+            manifest = []
+            for i, ((key, _), arr) in enumerate(zip(named, arrays)):
+                arr = np.asarray(arr)
+                true_dtype = str(arr.dtype)
+                if true_dtype in _VIEW_AS:
+                    arr = arr.view(_VIEW_AS[true_dtype])
+                fname = f"leaf_{i:05d}.npy"
+                np.save(os.path.join(tmp, fname), arr)
+                manifest.append(
+                    {"key": key, "file": fname, "shape": list(arr.shape), "dtype": true_dtype}
+                )
+            with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                json.dump({"step": step, "leaves": manifest}, f)
         if on_commit is not None:
             on_commit(step, tmp)
         if os.path.exists(final):
             shutil.rmtree(final)
-        t_rename = time.monotonic()
-        with obs.tracer().span("ckpt.rename", "ckpt", step=step):
+        with tr.span("ckpt.rename", "ckpt", step=step):
             os.replace(tmp, final)  # atomic commit
-        met = obs.metrics()
-        met.histogram("ckpt.rename_s").observe(time.monotonic() - t_rename)
-        met.histogram("ckpt.save_s").observe(time.monotonic() - t_save)
-        # array payload only (manifest.json excluded): the packed-corpus
-        # contract is "bytes moved, never bytes written" — state checkpoints
-        # are pack-invariant, so this counter is how traces prove it
-        met.counter("ckpt.written_bytes").inc(written)
+        obs.metrics().histogram("ckpt.save_s").observe(time.monotonic() - t_save)
     return final
 
 
@@ -169,6 +183,7 @@ class AsyncCheckpointer:
         self._thread.start()
 
     def _run(self):
+        _WRITER.checkpointer = self
         while True:
             item = self._queue.get()
             try:

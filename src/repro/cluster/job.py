@@ -355,7 +355,6 @@ def run_scan_job(
 
     ran = 0
     tr = obs.tracer()
-    met = obs.metrics()
     if pipelined:
         stream_segs = segs[start_seg:]
         if first_segment is not None and start_seg == 0 and stream_segs:
@@ -408,25 +407,29 @@ def run_scan_job(
                             f"injected failure before segment {seg_idx} commit"
                         )
                 a, _ = segs[seg_idx]
-                t_fold = time.monotonic()
+                # the fold's *dispatch*: the device runs it asynchronously,
+                # and the program first waits on it in this segment's
+                # ckpt.fetch (on the writer thread when pipelined)
                 with tr.span("segment.fold", "job", shard=shard, segment=seg_idx):
                     state = fold(
                         state, queries, seg_docs, stats, np.int32(doc_id_offset + a)
                     )
-                met.histogram("job.segment_fold_s").observe(time.monotonic() - t_fold)
                 ran += 1
                 if ckpt_dir:
                     on_commit = (
                         faults.commit_hook(shard, seg_idx, attempt) if faults else None
                     )
-                    save_kw = {} if on_commit is None else {"on_commit": on_commit}
+                    save_kw = {"shard": shard}
+                    if on_commit is not None:
+                        save_kw["on_commit"] = on_commit
                     if writer is not None:
                         # commit off the critical path; submission order keeps
                         # the on-disk sequence identical to the sync path's
                         # (an injected writer error poisons this writer exactly
                         # like a real I/O failure: later tasks skipped, error
-                        # re-raised at the next drain). The actual save/rename
-                        # spans appear on the writer thread (ckpt.save).
+                        # re-raised at the next drain). The actual save spans
+                        # (ckpt.save > fetch / write / rename) appear on the
+                        # writer thread.
                         with tr.span(
                             "segment.commit_submit", "ckpt",
                             shard=shard, segment=seg_idx,

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
+
 
 def _grades_at_ranks(run_ids: np.ndarray, qrels: np.ndarray) -> np.ndarray:
     """Relevance grade of each ranked position, 0 for empty (-1) slots."""
@@ -102,15 +104,20 @@ def evaluate_run(
     and per-query vectors back the significance test.
     """
     depth = np.asarray(run_ids).shape[1]
-    per_query: dict[str, np.ndarray] = {
-        "ap": average_precision(run_ids, qrels),
-        "rr": reciprocal_rank(run_ids, qrels),
-    }
     for k in ks:
         if k > depth:
             raise ValueError(f"cutoff {k} exceeds run depth {depth}")
+    # one span per measure (its name says which), so a trace shows where
+    # evaluation spends its time
+    tr = obs.tracer()
+    per_query: dict[str, np.ndarray] = {}
+    for short, fn in PER_QUERY_METRICS.items():
+        with tr.span(f"eval.{short}", "eval"):
+            per_query[short] = fn(run_ids, qrels)
+    for k in ks:
         for short, fn in AT_K_METRICS.items():
-            per_query[f"{short}@{k}"] = fn(run_ids, qrels, k)
+            with tr.span(f"eval.{short}", "eval", k=k):
+                per_query[f"{short}@{k}"] = fn(run_ids, qrels, k)
     aggregate = {
         "map" if name == "ap" else "mrr" if name == "rr" else name: float(v.mean())
         for name, v in per_query.items()

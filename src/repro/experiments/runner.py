@@ -319,16 +319,17 @@ def _run_experiment_traced(
 
         significance = {}
         baseline = spec.baseline if spec.baseline in per_query_ap else scorers[0].name
-        for name, ap in per_query_ap.items():
-            if name == baseline:
-                continue
-            res = paired_randomization_test(ap, per_query_ap[baseline], seed=seed)
-            significance[name] = {
-                "vs": baseline,
-                "metric": "ap",
-                "diff": res.diff,
-                "p_value": res.p_value,
-            }
+        with tr.span("eval.significance", "eval"):
+            for name, ap in per_query_ap.items():
+                if name == baseline:
+                    continue
+                res = paired_randomization_test(ap, per_query_ap[baseline], seed=seed)
+                significance[name] = {
+                    "vs": baseline,
+                    "metric": "ap",
+                    "diff": res.diff,
+                    "p_value": res.p_value,
+                }
 
     obs_block = None
     if trace_out is not None:

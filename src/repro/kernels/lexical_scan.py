@@ -210,5 +210,6 @@ def lexical_scan_topk_pallas(
         scratch_shapes=[pltpu.VMEM((length, block_d), jnp.int32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="lexical_scan",  # the kernel's name in device traces
     )(q_col, w_t, ab, d_tokens, d_len.reshape(1, n_d))
     return scores[:, :n_q, :k], ids[:, :n_q, :k]

@@ -232,5 +232,6 @@ def score_topk_pallas(
         scratch_shapes=[pltpu.VMEM((n_rows, block_d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="score_topk",  # the kernel's name in device traces
     )(q, d)
     return scores[:n_q, :k], ids[:n_q, :k]

@@ -23,6 +23,11 @@ or pass ``--trace-out trace.json`` to ``repro.launch.experiment``, which
 wraps the whole lifecycle and writes the Chrome trace, the JSONL event
 log, and the ``report.json`` ``job.obs`` rollup.
 
+While an enabled tracer is installed, JAX's own compile-path events land
+in it as ``jit.trace`` / ``jit.lower`` / ``jit.compile`` /
+``jit.cache_load`` spans (:func:`watch_compiles`): a jit cache miss inside
+a measured stretch names itself.
+
 The globals are plain module state, not contextvars, on purpose: the
 instrumented layers hand work to long-lived helper threads (scheduler
 workers, the checkpoint writer, the prefetch producer) that must record
@@ -40,6 +45,7 @@ from __future__ import annotations
 import contextlib
 import platform
 import sys
+import threading
 
 from repro.obs import export
 from repro.obs.metrics import Counter, Gauge, Histogram, Metrics, latency_buckets
@@ -60,6 +66,7 @@ __all__ = [
     "provenance",
     "session",
     "tracer",
+    "watch_compiles",
 ]
 
 # the process defaults: tracing off (guard-checked no-op), metrics on
@@ -84,15 +91,58 @@ def install(
     """Swap the active instruments; returns the previous pair (for restore).
 
     ``None`` leaves that instrument unchanged. Prefer :func:`session` in
-    tests — it restores on exit.
+    tests — it restores on exit. An enabled tracer also gets the compile
+    spans (:func:`watch_compiles`), from its ``jit.watch`` instant on.
     """
     global _TRACER, _METRICS
     prev = (_TRACER, _METRICS)
     if tracer is not None:
         _TRACER = tracer
+        if tracer.enabled:
+            watch_compiles()
+            # from here on this tracer holds the process's compile spans
+            tracer.instant("jit.watch", "jit")
     if metrics is not None:
         _METRICS = metrics
     return prev
+
+
+# JAX's duration events on the compile path (``jax/_src/dispatch.py``,
+# ``jax/_src/compiler.py``) and the span each becomes. A persistent-cache hit
+# fires ``jit.cache_load`` inside the ``jit.compile`` that wraps the lookup.
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jit.cache_load",
+}
+_WATCH_LOCK = threading.Lock()
+_WATCHING = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kwargs) -> None:
+    tr = _TRACER
+    if not tr.enabled:
+        return
+    name = JIT_EVENTS.get(event)
+    if name is not None:
+        t1 = tr.now()  # JAX reports at the event's end
+        tr.record(name, t1 - duration_secs, t1, "jit", fun=kwargs.get("fun_name", ""))
+
+
+def watch_compiles() -> None:
+    """Record JAX's trace, lower, compile and cache-load events as ``jit.*``
+    spans of whichever tracer is active when they fire (nothing while it is
+    disabled). One listener for the process, registered on the first call;
+    :func:`install` of an enabled tracer makes that call."""
+    global _WATCHING
+    with _WATCH_LOCK:
+        if _WATCHING:
+            return
+        import jax.monitoring  # deferred: obs must import without jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _WATCHING = True
 
 
 @contextlib.contextmanager

@@ -158,6 +158,10 @@ class Tracer:
 
     # -- recording (the fast paths) -----------------------------------------
 
+    def now(self) -> float:
+        """The tracer clock's current reading."""
+        return self._clock()
+
     def span(self, name: str, cat: str = "", **attrs: Any):
         """Context manager timing its body; records on exit (even on error)."""
         if not self.enabled:

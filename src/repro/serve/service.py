@@ -82,8 +82,6 @@ class RejectedError(RuntimeError):
 # batch sizes are small integers bucketed like the padder buckets them:
 # powers of two (latency buckets would waste resolution below 1.0)
 _BATCH_BOUNDS = tuple(float(1 << i) for i in range(11))  # 1 .. 1024
-# occupancy is a fraction: linear buckets resolve the whole [0, 1] range
-_OCCUPANCY_BOUNDS = tuple(i / 10 for i in range(1, 11))
 
 
 class RetrievalService:
@@ -136,6 +134,7 @@ class RetrievalService:
             for kind, sess in self.sessions.items()
         }
         self._next_rid = 0
+        self._next_block = 0  # dispatch sequence number, on the serve.* spans
         self.metrics: list[BatchRecord] = []
         self.admission = admission
         self.policy = policy
@@ -230,10 +229,12 @@ class RetrievalService:
     def _dispatch(self, kind: str, block: QueryBlock) -> dict[int, SearchResult]:
         session = self.sessions[kind]
         tr = obs.tracer()
+        seq = self._next_block
+        self._next_block += 1
         t0 = self._clock()
         with tr.span(
             "serve.dispatch", "serve",
-            kind=kind, n_real=block.n_real, n_padded=block.n_padded,
+            kind=kind, block=seq, n_real=block.n_real, n_padded=block.n_padded,
             trigger=block.trigger,
         ):
             state = session.search(block.queries)
@@ -252,31 +253,31 @@ class RetrievalService:
         met.counter("serve.requests").inc(block.n_real)
         met.counter("serve.batches").inc()
         met.histogram("serve.batch_size", bounds=_BATCH_BOUNDS).observe(block.n_real)
-        met.histogram(
-            "serve.batch_occupancy", bounds=_OCCUPANCY_BOUNDS
-        ).observe(block.n_real / block.n_padded)
         met.histogram("serve.queue_wait_s").observe(
             block.closed_at - block.oldest_arrival
         )
         met.histogram("serve.latency_s").observe(latency)
-        # per-request lifecycle spans (enqueue → reply), recorded at reply
-        # time on the service clock (== the tracer clock in production)
+        with tr.span("serve.reply", "serve", kind=kind, block=seq):
+            scores = unpad_results(np.asarray(state.scores), block.n_real)
+            ids = unpad_results(np.asarray(state.ids), block.n_real)
+            out = {
+                rid: SearchResult(rid=rid, scores=scores[row], ids=ids[row])
+                for row, rid in enumerate(block.rids)
+            }
+        # per-request lifecycle, admission -> results on the host, on the
+        # service clock (== the tracer clock in production)
         done = self._clock()
-        request_hist = met.histogram("serve.request_s")
-        recent = (
-            met.histogram("serve.recent.request_s") if self.policy is not None else None
-        )
-        for rid, arrival in zip(block.rids, block.arrivals):
-            tr.record("serve.request", arrival, done, "serve", rid=rid, kind=kind)
-            request_hist.observe(done - arrival)
-            if recent is not None:
+        if tr.enabled:
+            for rid, arrival in zip(block.rids, block.arrivals):
+                tr.record(
+                    "serve.request", arrival, done, "serve", rid=rid, kind=kind,
+                    block=seq, queued_s=block.closed_at - arrival,
+                )
+        if self.policy is not None:
+            recent = met.histogram("serve.recent.request_s")
+            for arrival in block.arrivals:
                 recent.observe(done - arrival)
-        scores = unpad_results(np.asarray(state.scores), block.n_real)
-        ids = unpad_results(np.asarray(state.ids), block.n_real)
-        return {
-            rid: SearchResult(rid=rid, scores=scores[row], ids=ids[row])
-            for row, rid in enumerate(block.rids)
-        }
+        return out
 
     def poll(self, limit: int | None = None) -> dict[int, SearchResult]:
         """Dispatch every block whose size/deadline trigger has fired

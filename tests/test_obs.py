@@ -13,14 +13,18 @@ warnings must point at the *caller's* line at every entry point —
 ``run_scan_job``, ``run_sharded_scan_job``, and ``run_experiment``.
 """
 
+import collections
 import json
 import threading
 import warnings
+from types import SimpleNamespace
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import checkpoint as ckpt
 from repro import cluster, obs
 from repro.cluster.faults import FaultSchedule, FaultSpec, WorkerCrash
 from repro.core import anchors
@@ -286,7 +290,11 @@ def test_sharded_job_emits_spans_per_shard(collection, tmp_path):
     assert {s.attrs["outcome"] for s in attempts} == {"ok"}
     # checkpoint commits happen on the writer thread, visible as its spans
     assert all(s.tname == "ckpt-writer" for s in tr.spans("ckpt.save"))
-    assert met.histogram("job.segment_fold_s").count == 2 * N_SHARDS
+    # one fetch (the first wait on a segment's fold) per committed segment
+    fetches = tr.spans("ckpt.fetch")
+    assert sorted((s.attrs["shard"], s.attrs["step"]) for s in fetches) == [
+        (shard, step) for shard in range(N_SHARDS) for step in (1, 2)
+    ]
     assert met.summary()["histograms"]["ckpt.save_s"]["count"] >= 2 * N_SHARDS
 
 
@@ -366,7 +374,9 @@ def test_traced_run_files_byte_identical_to_untraced(tmp_path):
     doc = json.load(open(trace_path))
     folds = [e for e in doc["traceEvents"] if e["name"] == "segment.fold"]
     assert {e["args"]["shard"] for e in folds} == set(range(N_SHARDS))
-    assert ob["metrics"]["histograms"]["job.segment_fold_s"]["count"] >= 4
+    fetches = [e for e in doc["traceEvents"] if e["name"] == "ckpt.fetch"]
+    assert len(fetches) >= 4  # one per committed segment, retries included
+    assert {e["args"]["shard"] for e in fetches} == set(range(N_SHARDS))
     assert "shard 0" in ob["phases"]
     assert trace_path.with_suffix(".jsonl").exists()
     # ...and never perturbed the artifacts
@@ -420,6 +430,127 @@ def test_serve_dispatch_populates_histograms_and_request_spans():
     dispatches = tr.spans("serve.dispatch")
     assert [d.attrs["n_real"] for d in dispatches] == [4, 4, 2]
     assert {d.attrs["trigger"] for d in dispatches} == {"size", "flush"}
+
+
+class _StubSession:
+    """A session with no device work: zero scores for every padded row."""
+
+    pad_value = 0
+
+    def search(self, q):
+        n = q.shape[0]
+        return SimpleNamespace(
+            scores=np.zeros((n, 2), np.float32), ids=np.zeros((n, 2), np.int32)
+        )
+
+
+def test_serve_request_carries_block_and_batcher_wait_and_ends_after_reply():
+    clock = StepClock(dt=0.25)  # one clock for the service and the tracer
+    tr = Tracer(clock=clock)
+    service = RetrievalService(
+        {"stub": _StubSession()}, max_batch=2, max_delay=100.0, min_bucket=2,
+        clock=clock, registry=Metrics(),
+    )
+    with obs.session(tr):
+        rids = [service.submit(np.ones(3, np.int32), "stub") for _ in range(3)]
+        service.poll()  # the size trigger closes block 0 (two requests)
+        service.drain()  # the flush closes block 1
+    dispatch = {s.attrs["block"]: s for s in tr.spans("serve.dispatch")}
+    reply = {s.attrs["block"]: s for s in tr.spans("serve.reply")}
+    assert sorted(dispatch) == sorted(reply) == [0, 1]
+    reqs = sorted(tr.spans("serve.request"), key=lambda s: s.attrs["rid"])
+    assert [r.attrs["rid"] for r in reqs] == rids
+    assert [r.attrs["block"] for r in reqs] == [0, 0, 1]
+    for r in reqs:
+        b = r.attrs["block"]
+        closed = r.ts + r.attrs["queued_s"]  # admission + wait = block close
+        assert r.attrs["queued_s"] > 0 and closed <= dispatch[b].ts
+        assert r.ts + r.dur >= reply[b].ts + reply[b].dur
+        assert dispatch[b].ts + dispatch[b].dur <= reply[b].ts
+    # the oldest request of a block waited the block's own queue wait
+    assert reqs[0].attrs["queued_s"] == pytest.approx(service.metrics[0].queue_wait_s)
+    assert reqs[2].attrs["queued_s"] == pytest.approx(service.metrics[1].queue_wait_s)
+    assert reqs[0].ts + reqs[0].attrs["queued_s"] == pytest.approx(
+        reqs[1].ts + reqs[1].attrs["queued_s"]
+    )
+
+
+def test_disabled_tracer_records_nothing_on_the_instrumented_paths(tmp_path):
+    obs.watch_compiles()  # the listener is live, and must stay silent
+    tr = Tracer(enabled=False)
+    service = RetrievalService(
+        {"stub": _StubSession()}, max_batch=2, min_bucket=2, registry=Metrics()
+    )
+    with obs.session(tr):
+        service.submit(np.ones(3, np.int32), "stub")
+        service.drain()
+        ckpt.save(str(tmp_path), 1, {"x": jnp.arange(5)})
+        jax.jit(lambda x: x - 2)(np.arange(3)).block_until_ready()
+    assert len(tr) == 0
+
+
+def test_checkpoint_fetch_and_write_nest_in_save_on_the_writer_thread(
+    collection, tmp_path
+):
+    with obs.session() as (tr, _):
+        _run_job(collection, tmp_path)
+    saves = {(s.attrs["shard"], s.attrs["step"]): s for s in tr.spans("ckpt.save")}
+    committed = [(shard, step) for shard in range(N_SHARDS) for step in (1, 2)]
+    assert sorted(saves) == committed
+    for name in ("ckpt.fetch", "ckpt.write"):
+        inner = tr.spans(name)
+        assert sorted((s.attrs["shard"], s.attrs["step"]) for s in inner) == committed
+        for s in inner:
+            outer = saves[s.attrs["shard"], s.attrs["step"]]
+            assert s.tname == "ckpt-writer" and s.tid == outer.tid
+            assert outer.ts <= s.ts and s.ts + s.dur <= outer.ts + outer.dur
+    fetch = {(s.attrs["shard"], s.attrs["step"]): s for s in tr.spans("ckpt.fetch")}
+    for s in tr.spans("ckpt.write"):
+        f = fetch[s.attrs["shard"], s.attrs["step"]]
+        assert f.ts + f.dur <= s.ts  # the leaves are on the host before the files
+        assert isinstance(f.attrs["queued"], int) and f.attrs["queued"] >= 0
+
+
+def test_traced_experiment_names_each_eval_measure_inside_experiment_eval(tmp_path):
+    spec = exp_grid.ExperimentSpec(
+        name="obs-eval", grids=(exp_grid.GridSpec("bm25"), exp_grid.GridSpec("ql_lm")),
+        n_docs=N_DOCS, n_queries=4, vocab=VOCAB, max_doc_len=24,
+        k=K, chunk_size=CHUNK, segment_chunks=2,
+    )
+    with obs.session() as (tr, _):
+        runner.run_experiment(spec, out_dir=str(tmp_path / "e"), seed=3)
+    (ev,) = tr.spans("experiment.eval")
+    parts = [s for s in tr.spans() if s.name.startswith("eval.")]
+    ks = [c for c in spec.eval_ks if c <= K] or [K]
+    assert collections.Counter(s.name for s in parts) == {
+        "eval.ap": 2, "eval.rr": 2, "eval.p": 2 * len(ks), "eval.recall": 2 * len(ks),
+        "eval.ndcg": 2 * len(ks), "eval.significance": 1,
+    }
+    assert sorted(s.attrs["k"] for s in parts if s.name == "eval.ndcg") == sorted(ks * 2)
+    for s in parts:
+        assert s.tid == ev.tid and ev.ts <= s.ts and s.ts + s.dur <= ev.ts + ev.dur
+
+
+def test_compile_listener_records_each_new_executable_once():
+    obs.watch_compiles()
+    obs.watch_compiles()  # idempotent: a second listener would double each span
+
+    def add_seven(x):
+        return x + 7
+
+    f = jax.jit(add_seven)
+    x = np.arange(11, dtype=np.float32)
+    with obs.session() as (tr, _):
+        f(x).block_until_ready()
+        first = len(tr)
+        f(x).block_until_ready()  # cached: nothing compiles
+        assert len(tr) == first
+    assert len(tr.instants("jit.watch")) == 1
+    (comp,) = tr.spans("jit.compile")
+    assert "add_seven" in comp.attrs["fun"] and comp.cat == "jit"
+    assert len(tr.spans("jit.cache_load")) <= 1  # a persistent-cache hit, if any
+    assert tr.spans("jit.trace") and tr.spans("jit.lower")
+    assert all(s.dur >= 0 for s in tr.spans())
 
 
 # -- deprecation alias origin (satellite) -------------------------------------

@@ -223,10 +223,10 @@ def test_async_writer_error_fails_the_job(collection, tmp_path, monkeypatch):
     scorers = [scoring.make_variant("ql_lm")]
     real_save = ckpt.save
 
-    def failing_save(ckpt_dir, step, tree):
+    def failing_save(ckpt_dir, step, tree, **kw):
         if step == 2:
             raise OSError("disk full (injected)")
-        return real_save(ckpt_dir, step, tree)
+        return real_save(ckpt_dir, step, tree, **kw)
 
     monkeypatch.setattr(ckpt, "save", failing_save)
     with pytest.raises(OSError, match="disk full"):
