@@ -2,15 +2,18 @@
 
 The measurement half of the batch experiment engine (`repro.experiments`):
 scan jobs produce run files, this package turns (run, qrels) into MAP / P@k /
-NDCG / MRR / recall report cards and paired-randomization p-values between
+NDCG / MRR / recall report cards, read from a compact `judgments` view of the
+qrels built once per experiment, and paired-randomization p-values between
 runs. Also the single source of truth for quality numbers elsewhere in the
 repo (`benchmarks/quality_pk.py` asserts through these functions).
 """
 
 from repro.eval import metrics, significance, trec
 from repro.eval.metrics import (
+    Judgments,
     average_precision,
     evaluate_run,
+    judgments,
     ndcg_at_k,
     precision_at_k,
     recall_at_k,
@@ -23,8 +26,10 @@ __all__ = [
     "metrics",
     "significance",
     "trec",
+    "Judgments",
     "average_precision",
     "evaluate_run",
+    "judgments",
     "ndcg_at_k",
     "precision_at_k",
     "recall_at_k",

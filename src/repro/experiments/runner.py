@@ -31,7 +31,7 @@ from repro import obs, tune
 from repro.cluster import FaultSchedule, plan_shards, run_sharded_scan_job
 from repro.core import anchors, packing, topk
 from repro.data import synthetic
-from repro.eval import evaluate_run, paired_randomization_test, trec
+from repro.eval import evaluate_run, judgments, paired_randomization_test, trec
 from repro.experiments.grid import ExperimentSpec
 from repro.tune import TuningConfig
 
@@ -308,12 +308,14 @@ def _run_experiment_traced(
         trec.write_qrels(os.path.join(out_dir, "qrels.txt"), coll.qrels)
 
     with tr.span("experiment.eval", "experiment"):
+        # one pass over the qrels matrix, shared by every measure of every model
+        with tr.span("eval.judgments", "eval") as sp:
+            judged = judgments(coll.qrels, max(spec.eval_ks))
+            sp.set(n_judged=judged.n_judged, n_docs=judged.qrels.shape[1])
         reports = {}
         per_query_ap = {}
         for m, s in enumerate(scorers):
-            rep = evaluate_run(
-                np.asarray(job.state.ids)[m], coll.qrels, ks=spec.eval_ks
-            )
+            rep = evaluate_run(np.asarray(job.state.ids)[m], judged, ks=spec.eval_ks)
             reports[s.name] = rep["aggregate"]
             per_query_ap[s.name] = rep["per_query"]["ap"]
 

@@ -1,5 +1,6 @@
 """repro.eval: metrics against hand-computed values, TREC I/O, significance."""
 
+import _eval_reference as ref
 import numpy as np
 import pytest
 
@@ -87,6 +88,100 @@ def test_evaluate_run_aggregates():
     }
     with pytest.raises(ValueError, match="exceeds run depth"):
         metrics.evaluate_run(RUN, QRELS, ks=(5,))
+
+
+def _graded(rng, n_q, n_docs, per_query, grades):
+    qrels = np.zeros((n_q, n_docs), np.int8)
+    for q in range(n_q):
+        docs = rng.choice(n_docs, size=per_query, replace=False)
+        qrels[q, docs] = rng.choice(grades, size=per_query)
+    return qrels
+
+
+def _case(name):
+    """(run [n_q, depth], qrels, cutoffs) for one equivalence case; cutoffs
+    may run deeper than the run or the collection."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    n_q, n_docs, depth, ks = 6, 256, 30, (5, 10, 20)
+    per_query, grades = 12, (1, 2, 3)
+    if name == "negative_grades":  # rows nearly all judged: negatives reach top 20
+        n_docs, per_query, grades = 24, 20, (-2, -1, 1, 2, 3)
+    elif name == "n_docs_below_k":
+        n_docs, depth, per_query, grades = 12, 24, 8, (-2, 1, 2, 3)
+    elif name == "judged_beyond_k":
+        per_query = 60
+    elif name == "ragged_row_width":
+        n_q, n_docs = 5, 203  # 1,015 bytes: words and a 7-byte tail
+    elif name == "shallow_run":
+        depth = 8
+    qrels = _graded(rng, n_q, n_docs, per_query, grades)
+    if name == "bool":
+        qrels = qrels > 0
+    if name == "unjudged_query":
+        qrels[2] = 0
+    if name == "ragged_row_width":
+        qrels[-1, -1] = 2  # a judgment in the tail
+    if name == "column_major":  # idcg sums in the layout's order, as the sort did
+        qrels = np.asfortranarray(qrels)
+    # a run that retrieves some judged documents among unjudged ones
+    run = np.full((n_q, depth), -1)
+    for q in range(n_q):
+        run[q, : min(n_docs, depth)] = rng.permutation(n_docs)[:depth]
+    judged = np.flatnonzero(qrels[0])
+    run[0, : min(len(judged), depth)] = judged[:depth]
+    if name == "empty_slots":
+        run[:, depth // 2 :] = -1
+        run[1] = -1
+    return run, qrels, ks
+
+
+EQUIVALENCE_CASES = [
+    "sparse_grades", "bool", "negative_grades", "unjudged_query", "empty_slots",
+    "shallow_run", "n_docs_below_k", "judged_beyond_k", "ragged_row_width",
+    "column_major",
+]
+
+
+@pytest.mark.parametrize("name", EQUIVALENCE_CASES)
+def test_measures_bitwise_equal_to_dense_reference(name):
+    run, qrels, ks = _case(name)
+    view = metrics.judgments(qrels, max(ks))
+    full = np.sort(qrels.astype(np.float64), axis=1)[:, ::-1][:, : max(ks)]
+    assert np.array_equal(view.ideal, full)
+    assert view.qrels is qrels and view.n_judged == np.count_nonzero(qrels)
+    assert np.array_equal(view.n_rel, (qrels > 0).sum(axis=1))
+    for q in (qrels, view):
+        for fn in ("average_precision", "reciprocal_rank"):
+            want = getattr(ref, fn)(run, qrels)
+            assert np.array_equal(getattr(metrics, fn)(run, q), want), fn
+        for k in (*ks, run.shape[1] + 3):
+            for fn in ("precision_at_k", "recall_at_k", "ndcg_at_k"):
+                if q is view and fn == "ndcg_at_k" and k > max(ks):
+                    continue  # deeper than the view's ideal ranks
+                want = getattr(ref, fn)(run, qrels, k)
+                assert np.array_equal(getattr(metrics, fn)(run, q, k), want), (fn, k)
+    within = tuple(k for k in ks if k <= run.shape[1])
+    want = ref.evaluate(run, qrels, within)
+    got = metrics.evaluate_run(run, qrels, ks=within)
+    assert got["aggregate"] == want["aggregate"]
+    assert got["per_query"].keys() == want["per_query"].keys()
+    for key, v in want["per_query"].items():
+        assert np.array_equal(got["per_query"][key], v), key
+
+
+def test_evaluate_run_from_judgments_equals_from_matrix():
+    run, qrels, ks = _case("negative_grades")
+    from_view = metrics.evaluate_run(run, metrics.judgments(qrels, max(ks)), ks=ks)
+    from_matrix = metrics.evaluate_run(run, qrels, ks=ks)
+    assert from_view["aggregate"] == from_matrix["aggregate"]
+    for key, v in from_matrix["per_query"].items():
+        assert np.array_equal(from_view["per_query"][key], v), key
+
+
+def test_ndcg_refuses_judgments_shallower_than_k():
+    run, qrels, _ = _case("sparse_grades")
+    with pytest.raises(ValueError, match="ideal ranks to 5"):
+        metrics.ndcg_at_k(run, metrics.judgments(qrels, 5), 10)
 
 
 def test_trec_run_roundtrip(tmp_path):

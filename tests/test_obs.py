@@ -19,6 +19,7 @@ import threading
 import warnings
 from types import SimpleNamespace
 
+import _eval_reference as eval_ref
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +30,7 @@ from repro import cluster, obs
 from repro.cluster.faults import FaultSchedule, FaultSpec, WorkerCrash
 from repro.core import anchors
 from repro.data import synthetic
+from repro.eval import paired_randomization_test, trec
 from repro.experiments import grid as exp_grid
 from repro.experiments import runner
 from repro.obs import export
@@ -511,24 +513,64 @@ def test_checkpoint_fetch_and_write_nest_in_save_on_the_writer_thread(
         assert isinstance(f.attrs["queued"], int) and f.attrs["queued"] >= 0
 
 
+EVAL_SPEC = exp_grid.ExperimentSpec(
+    name="obs-eval", grids=(exp_grid.GridSpec("bm25"), exp_grid.GridSpec("ql_lm")),
+    n_docs=N_DOCS, n_queries=4, vocab=VOCAB, max_doc_len=24,
+    k=K, chunk_size=CHUNK, segment_chunks=2,
+)
+
+
 def test_traced_experiment_names_each_eval_measure_inside_experiment_eval(tmp_path):
-    spec = exp_grid.ExperimentSpec(
-        name="obs-eval", grids=(exp_grid.GridSpec("bm25"), exp_grid.GridSpec("ql_lm")),
-        n_docs=N_DOCS, n_queries=4, vocab=VOCAB, max_doc_len=24,
-        k=K, chunk_size=CHUNK, segment_chunks=2,
-    )
+    spec = EVAL_SPEC
+    coll = runner.prepare_collection(spec, seed=3)
     with obs.session() as (tr, _):
-        runner.run_experiment(spec, out_dir=str(tmp_path / "e"), seed=3)
-    (ev,) = tr.spans("experiment.eval")
+        for i in range(2):
+            runner.run_experiment(
+                spec, out_dir=str(tmp_path / f"e{i}"), seed=3, collection=coll
+            )
+    evs = tr.spans("experiment.eval")
+    assert len(evs) == 2
     parts = [s for s in tr.spans() if s.name.startswith("eval.")]
     ks = [c for c in spec.eval_ks if c <= K] or [K]
     assert collections.Counter(s.name for s in parts) == {
-        "eval.ap": 2, "eval.rr": 2, "eval.p": 2 * len(ks), "eval.recall": 2 * len(ks),
-        "eval.ndcg": 2 * len(ks), "eval.significance": 1,
+        "eval.judgments": 2, "eval.ap": 4, "eval.rr": 4, "eval.p": 4 * len(ks),
+        "eval.recall": 4 * len(ks), "eval.ndcg": 4 * len(ks), "eval.significance": 2,
     }
-    assert sorted(s.attrs["k"] for s in parts if s.name == "eval.ndcg") == sorted(ks * 2)
-    for s in parts:
-        assert s.tid == ev.tid and ev.ts <= s.ts and s.ts + s.dur <= ev.ts + ev.dur
+    assert sorted(s.attrs["k"] for s in parts if s.name == "eval.ndcg") == sorted(ks * 4)
+    for ev in evs:  # one pass over the qrels per experiment, before any measure
+        inside = [s for s in parts if ev.ts <= s.ts and s.ts + s.dur <= ev.ts + ev.dur]
+        assert len(inside) == len(parts) // 2 and all(s.tid == ev.tid for s in inside)
+        (jd,) = [s for s in inside if s.name == "eval.judgments"]
+        assert jd.attrs == {
+            "n_judged": np.count_nonzero(coll.qrels), "n_docs": spec.n_docs
+        }
+        assert all(jd.ts + jd.dur <= s.ts for s in inside if s is not jd)
+
+
+def test_report_metrics_and_significance_equal_the_dense_reference(tmp_path):
+    spec = EVAL_SPEC
+    coll = runner.prepare_collection(spec, seed=5)
+    report = runner.run_experiment(
+        spec, out_dir=str(tmp_path / "e"), seed=5, collection=coll
+    )
+    with open(tmp_path / "e" / "report.json") as f:
+        written = json.load(f)
+    ks = tuple(c for c in spec.eval_ks if c <= K) or (K,)
+    per_query_ap = {}
+    for name, path in report["runs"].items():
+        ids, _, _ = trec.read_run(path, depth=K)
+        want = eval_ref.evaluate(ids, coll.qrels, ks)
+        assert written["metrics"][name] == want["aggregate"]
+        per_query_ap[name] = want["per_query"]["ap"]
+    base = written["baseline"]
+    want_sig = {}
+    for name, ap in per_query_ap.items():
+        if name != base:
+            res = paired_randomization_test(ap, per_query_ap[base], seed=5)
+            want_sig[name] = {
+                "vs": base, "metric": "ap", "diff": res.diff, "p_value": res.p_value
+            }
+    assert written["significance"] == want_sig and want_sig
 
 
 def test_compile_listener_records_each_new_executable_once():
