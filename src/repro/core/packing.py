@@ -46,6 +46,7 @@ two different pack specs can never alias one trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -187,6 +188,43 @@ def pack_tokens(tokens: Any, spec: PackSpec) -> np.ndarray:
         plane = (padded >> np.uint32(p)) & np.uint32(1)  # [n, g, 32]
         words[:, :, p] = np.bitwise_or.reduce(plane << shifts, axis=-1)
     return words.reshape(n, groups * spec.bits).view(np.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _pack_tokens_jnp(tokens, spec: PackSpec):
+    t = tokens.astype(jnp.int32)
+    n_bad = jnp.sum((t != PAD_TOKEN) & ((t < 0) | (t >= spec.vocab)))
+    mapped = jnp.where(t == PAD_TOKEN, spec.vocab, t).astype(jnp.uint32)
+    if spec.mode == "u8":
+        return mapped.astype(jnp.uint8), n_bad
+    if spec.mode == "u16":
+        return mapped.astype(jnp.uint16), n_bad
+    n, l = mapped.shape
+    groups = -(-l // _GROUP)
+    padded = jnp.pad(mapped, ((0, 0), (0, groups * _GROUP - l))).reshape(n, groups, _GROUP)
+    shifts = jnp.arange(_GROUP, dtype=jnp.uint32)
+    # bit t of word p is bit p of token t: the bits are disjoint, so a sum is an or
+    words = jnp.stack(
+        [
+            jnp.sum(((padded >> jnp.uint32(p)) & jnp.uint32(1)) << shifts, axis=-1,
+                    dtype=jnp.uint32)
+            for p in range(spec.bits)
+        ],
+        axis=-1,
+    )
+    return jax.lax.bitcast_convert_type(words.reshape(n, groups * spec.bits), jnp.int32), n_bad
+
+
+def pack_tokens_device(tokens: jax.Array, spec: PackSpec) -> jax.Array:
+    """:func:`pack_tokens` where the tokens already live: on the device(s)
+    holding ``tokens``, keeping their sharding (rows pack independently), with
+    the same bits and the same refusal of values outside the vocabulary."""
+    packed, n_bad = _pack_tokens_jnp(tokens, spec)
+    if int(n_bad):
+        raise ValueError(
+            f"tokens outside [0, {spec.vocab}) ∪ {{PAD_TOKEN}} cannot be packed"
+        )
+    return packed
 
 
 def unpack_tokens(packed: Any, spec: PackSpec, *, pad_to: int | None = None):

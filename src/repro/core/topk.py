@@ -15,10 +15,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro import compat
 
 NEG_INF = float("-inf")
+REDUCE_SCOPE = "mesh_reduce"  # the name the mesh reduce's ops carry
 
 
 class TopKState(NamedTuple):
@@ -139,11 +141,20 @@ def merge_across_lex(state: TopKState, axis_name: str | tuple[str, ...]) -> TopK
     re-reducing to k between stages, bounding the gather buffer at
     ``axis_size·k``), but folding with :func:`merge_lex` so the mesh reduce
     and the host-loop reduce (`repro.cluster`) share one merge contract.
+
+    The gathers and merges are named :data:`REDUCE_SCOPE` twice: a
+    ``jax.named_scope`` (each op's ``op_name``) and an XLA frontend attribute
+    ``mirex_scope``, which a TPU device trace prints in each op's name, so a
+    trace reduction finds the cross-chip reduce by name.
     """
-    if isinstance(axis_name, (tuple, list)):
-        for a in axis_name:
-            state = merge_across_lex(state, a)
-        return state
+    axes = tuple(axis_name) if isinstance(axis_name, (tuple, list)) else (axis_name,)
+    with jax.named_scope(REDUCE_SCOPE), set_xla_metadata(mirex_scope=REDUCE_SCOPE):
+        for a in axes:
+            state = _merge_stage_lex(state, a)
+    return state
+
+
+def _merge_stage_lex(state: TopKState, axis_name: str) -> TopKState:
     gathered = TopKState(
         scores=jax.lax.all_gather(state.scores, axis_name, axis=0, tiled=False),
         ids=jax.lax.all_gather(state.ids, axis_name, axis=0, tiled=False),

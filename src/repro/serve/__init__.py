@@ -6,8 +6,9 @@ Public surface:
   (``try_submit`` for typed admission outcomes).
 * :class:`~repro.serve.session.LexicalSession` /
   :class:`~repro.serve.session.DenseSession` — resident-corpus scan state.
-* :class:`~repro.serve.session.ShardedLexicalSession` — the same session
-  surface with the corpus resident *sharded* across a JAX mesh, reducing
+* :class:`~repro.serve.session.ShardedLexicalSession` /
+  :class:`~repro.serve.session.ShardedDenseSession` — the same session
+  surfaces with the corpus resident *sharded* across a JAX mesh, reducing
   through the `repro.cluster` merge contract.
 * :class:`~repro.serve.microbatch.Microbatcher` — deadline/size triggers +
   MXU-bucket padding, capped ladder (importable standalone for tests).
@@ -43,7 +44,12 @@ from repro.serve.service import (
     RetrievalService,
     SearchResult,
 )
-from repro.serve.session import DenseSession, LexicalSession, ShardedLexicalSession
+from repro.serve.session import (
+    DenseSession,
+    LexicalSession,
+    ShardedDenseSession,
+    ShardedLexicalSession,
+)
 
 __all__ = [
     "AdaptiveBatchPolicy",
@@ -61,6 +67,7 @@ __all__ = [
     "RetrievalService",
     "SearchRequest",
     "SearchResult",
+    "ShardedDenseSession",
     "ShardedLexicalSession",
     "Shed",
     "TokenBucket",

@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import cluster
 from repro.configs.archs import mirex
 from repro.configs.shapes import MIREX_SHAPES
-from repro.core import packing, scan, scoring
+from repro.core import packing, scan, scoring, topk
 from repro.kernels import ops
 from repro.kernels.lexical_scan import lexical_scan_topk_pallas
 from repro.kernels.score_topk import score_topk_pallas
@@ -210,3 +210,57 @@ def test_search_mesh_kernel_four_chips(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+
+
+# the whole MS MARCO passage collection over the 2x2 mesh: 540 chunks of
+# 16,384 passages, 135 a chip, 128 tokens and 768 float32 dims a passage
+MARCO_DOCS, MARCO_LEN, MARCO_DIM, MARCO_CHUNK, BLOCK = 8_847_360, 128, 768, 16_384, 64
+
+
+def _marco_mesh(topo):
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    return mesh, NamedSharding(mesh, P(("data", "model"))), NamedSharding(mesh, P())
+
+
+def _reduce_is_named(text: str) -> bool:
+    """Every all-gather of the compiled program carries the reduce's name."""
+    gathers = [ln for ln in text.splitlines() if " all-gather(" in ln]
+    return bool(gathers) and all(f'mirex_scope="{topk.REDUCE_SCOPE}"' in ln for ln in gathers)
+
+
+def test_search_mesh_whole_marco_lexical_four_chips(topo):
+    """The sharded lexical serve program of the whole-collection deployment:
+    the kernel on every chip's 2,211,840 passages, the reduce named in the
+    compiled program, and the program within a chip's memory beside its
+    1.13 GB of tokens."""
+    mesh, doc_sh, repl = _marco_mesh(topo)
+    docs = (
+        _shape((MARCO_DOCS, MARCO_LEN), jnp.int32, doc_sh),
+        _shape((MARCO_DOCS,), jnp.int32, doc_sh),
+    )
+    stats = _stats(repl)
+    fn = cluster.search_mesh(
+        mesh, jnp.zeros((1, 1), jnp.int32), docs, scoring.get_scorer("bm25"), k=CFG.k,
+        chunk_size=MARCO_CHUNK, stats=stats, use_kernel=True,
+    )
+    compiled = fn.lower(_shape((BLOCK, 8), jnp.int32, repl), docs, stats).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and _reduce_is_named(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_search_mesh_whole_marco_dense_four_chips(topo):
+    """The sharded dense serve program of the same deployment: the
+    score_topk kernel over each chip's 6.8 GB of vectors at the matmul
+    precision the configuration sets, the reduce named."""
+    mesh, doc_sh, repl = _marco_mesh(topo)
+    vectors = _shape((MARCO_DOCS, MARCO_DIM), jnp.float32, doc_sh)
+    with jax.default_matmul_precision("highest"):
+        fn = cluster.search_mesh(
+            mesh, jnp.zeros((1, MARCO_DIM), jnp.float32), vectors,
+            scoring.get_scorer("dense_dot"), k=CFG.k, chunk_size=MARCO_CHUNK, use_kernel=True,
+        )
+        compiled = fn.lower(_shape((BLOCK, MARCO_DIM), jnp.float32, repl), vectors, None).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and _reduce_is_named(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
