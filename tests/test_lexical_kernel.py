@@ -9,12 +9,14 @@ increase monotonically) and score-wise to fp32 tolerance. Plus: a whole
 model grid scanned in one kernel pass equals `scan.search_local_multi`.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import anchors, scan, scoring, topk
+from repro.core import anchors, packing, scan, scoring, topk
 from repro.data import synthetic
+from repro.kernels import lexical_scan
 
 VOCAB = 300
 CHUNK = 64
@@ -155,3 +157,102 @@ def test_non_multiple_chunk_raises(collection, use_kernel):
             queries, docs, scoring.get_scorer("ql_lm"), k=8, chunk_size=50,
             stats=stats, use_kernel=use_kernel,
         )
+
+
+# (L_q, block_d, query rows, token packing, models): geometries where the
+# register-blocked tf loop is cut differently — one slot group or several
+# (L_q 32), one document sub-block or several — and where the fold takes
+# one row tile, all eight, seven (50 rows) or two groups of six (96 rows)
+BLOCKING = [
+    (1, 128, 3, "none", 1),
+    (1, 512, 64, "16", 2),
+    (5, 128, 64, "none", 1),
+    (5, 512, 64, "16", 2),
+    (8, 128, 3, "16", 2),
+    (8, 512, 64, "none", 1),
+    (32, 128, 64, "16", 1),
+    (32, 512, 3, "none", 2),
+    (8, 512, 50, "none", 2),
+    (5, 128, 96, "16", 1),
+]
+
+
+@pytest.fixture(scope="module")
+def blocking_corpus():
+    n_docs, vocab, length = 1024, 300, 24
+    corpus = synthetic.make_corpus(n_docs=n_docs, vocab=vocab, max_len=length, seed=11)
+    toks, lens = jnp.asarray(corpus.tokens), jnp.asarray(corpus.lengths)
+    stats = anchors.collection_stats(toks, lens, vocab=vocab, chunk_size=128)
+    return corpus, stats
+
+
+@pytest.mark.parametrize(
+    "l_q, block_d, rows, pack, n_models", BLOCKING,
+    ids=lambda v: str(v),
+)
+def test_blocked_tf_bitwise_matches_host_fold(blocking_corpus, l_q, block_d, rows, pack, n_models):
+    """Scores and ids of the kernel are bitwise the host fold's, whatever
+    slot groups and document sub-blocks the tf loop is cut into and however
+    many row tiles each fold takes."""
+    corpus, stats = blocking_corpus
+    rng = np.random.default_rng(l_q * 1000 + block_d + rows)
+    # queries drawn from documents' own tokens, with pads in every row but
+    # the first (so some rows hold all L_q terms, some fewer)
+    docs_of = rng.integers(0, corpus.tokens.shape[0], rows)
+    q = np.full((rows, l_q), scoring.PAD_TOKEN, np.int32)
+    for i, d in enumerate(docs_of):
+        n = l_q if i == 0 else int(rng.integers(1, l_q + 1))
+        q[i, :n] = corpus.tokens[d, rng.integers(0, corpus.lengths[d], n)]
+    queries = jnp.asarray(q)
+    docs = jax.tree.map(
+        jnp.asarray,
+        packing.pack_corpus(corpus.tokens, corpus.lengths, vocab=300, mode=pack),
+    )
+    assert isinstance(docs, packing.PackedCorpus) == (pack != "none")
+    scorers = [scoring.get_scorer("bm25"), scoring.get_scorer("ql_lm")][:n_models]
+    host = scan.search_local_multi(
+        queries, docs, scorers, k=16, chunk_size=block_d, stats=stats
+    )
+    kern = scan.search_local_multi(
+        queries, docs, scorers, k=16, chunk_size=block_d, stats=stats, use_kernel=True
+    )
+    np.testing.assert_array_equal(np.asarray(kern.ids), np.asarray(host.ids))
+    np.testing.assert_array_equal(np.asarray(kern.scores), np.asarray(host.scores))
+    assert int((np.asarray(kern.ids) >= 0).sum()) > 0
+
+
+# every (L_q, block_d) the cells and the tuning space use: query slots 8
+# (`mirex`, `clueweb09b-web09`, `msmarco-passage`), the blocks `lex_block`
+# gives (chunks halved to 512) and `lex_block_d` values the tuning tests try
+GEOMETRY_SHAPES = [
+    (l_q, block_d)
+    for l_q in (1, 2, 4, 5, 8, 12, 16, 17, 32, 64)
+    for block_d in (32, 48, 64, 128, 256, 384, 512, 1024, 2048)
+]
+
+
+def test_tf_geometry_stays_within_the_vreg_budget():
+    """The live query column and accumulator of one slot group and
+    sub-block fit `TF_VREG_BUDGET`, the groups cover whole terms, and the
+    sub-blocks tile the block."""
+    for l_q, block_d in GEOMETRY_SHAPES:
+        terms, lanes = lexical_scan.tf_geometry(l_q, block_d)
+        vregs = 2 * terms * -(-lanes // 128)  # [terms * 8, lanes] int32, twice
+        assert vregs <= lexical_scan.TF_VREG_BUDGET, (l_q, block_d)
+        assert 1 <= terms <= l_q and block_d % lanes == 0, (l_q, block_d)
+        if block_d % 128 == 0:
+            assert lanes % 128 == 0, (l_q, block_d)
+        assert terms == l_q or 2 * (terms + 1) > lexical_scan.TF_VREG_BUDGET
+    # the serve and batch cells' shape, and the slot split
+    assert lexical_scan.tf_geometry(8, 512) == (8, 256)
+    assert lexical_scan.tf_geometry(32, 512) == (16, 128)
+
+
+def test_fold_tiles_divide_the_block():
+    """The fold takes whole groups of row tiles, at most `FOLD_TILES`."""
+    for tiles in range(1, 200):
+        g = lexical_scan.fold_tiles(tiles)
+        assert tiles % g == 0 and 1 <= g <= lexical_scan.FOLD_TILES
+        assert all(tiles % h for h in range(g + 1, lexical_scan.FOLD_TILES + 1))
+    # serve buckets of 8..64 rows, 50 queries of the batch cell, 96 rows
+    assert [lexical_scan.fold_tiles(t) for t in (1, 2, 4, 8, 7, 12)] == [1, 2, 4, 8, 7, 6]

@@ -24,7 +24,7 @@ from repro import cluster
 from repro.configs.archs import mirex
 from repro.configs.shapes import MIREX_SHAPES
 from repro.core import packing, scan, scoring, topk
-from repro.kernels import ops
+from repro.kernels import lexical_scan, ops
 from repro.kernels.lexical_scan import lexical_scan_topk_pallas
 from repro.kernels.score_topk import score_topk_pallas
 
@@ -128,6 +128,39 @@ def test_lexical_kernel_packed(one_chip, mode):
             _shape((2, 2), jnp.float32, one_chip),
             _shape((N_DOCS, spec.packed_width), spec.packed_dtype(), one_chip),
             _shape((N_DOCS,), jnp.int32, one_chip),
+        )
+        .compile()
+    )
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize(
+    "rows, l_q", [(64, 8), (16, 32)], ids=["marco-serve", "slot-split"]
+)
+def test_lexical_kernel_register_blocked(one_chip, rows, l_q):
+    """The register-blocked tf loop at the `msmarco-passage` serve shapes
+    (a 64-row block of 8-term queries over 128-token passages, 512-document
+    tiles, one `bm25` model, its 8 row tiles folded in one network), and
+    with 32-term queries, whose slots are split into groups of whole
+    terms."""
+    n_docs, length = 2_211_840, 128
+    assert lexical_scan.tf_geometry(l_q, 512)[0] < l_q or l_q <= 16
+    modes = (scoring.EpilogueMode("bm25"),)
+
+    def kernel(q, w, ab, tokens, lengths):
+        return lexical_scan_topk_pallas(
+            q, w, ab, tokens, lengths, modes=modes, k=CFG.k, block_d=512,
+            interpret=False,
+        )
+
+    compiled = (
+        jax.jit(kernel)
+        .lower(
+            _shape((rows, l_q), jnp.int32, one_chip),
+            _shape((1, rows, l_q), jnp.float32, one_chip),
+            _shape((1, 2), jnp.float32, one_chip),
+            _shape((n_docs, length), jnp.int32, one_chip),
+            _shape((n_docs,), jnp.int32, one_chip),
         )
         .compile()
     )
